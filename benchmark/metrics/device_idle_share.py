@@ -1,0 +1,8 @@
+"""device_idle_share: the share of the traced seconds in which no operation
+ran on the device (1 - union of device-op intervals over the window)."""
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace["busy_s"] / ctx.trace["window_s"])
