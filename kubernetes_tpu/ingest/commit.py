@@ -1,6 +1,6 @@
 """Batched assume/bind: the columnar commit edge of a drain.
 
-`_commit_assignments_inner` used to classify pods one by one
+The scheduler's commit used to classify pods one by one
 (`_needs_per_pod_hooks` re-deriving profile facts per pod) and
 `_fast_commit` then re-walked every pod's object graph inside
 `NodeInfo.add_pod` (affinity property chains, request-dict walks, a

@@ -275,6 +275,16 @@ SHARD_SEED_LANES = tuple(str(i) for i in range(8))
 # so dashboards see the series before the first split/steal/conflict
 SHARD_SEED_IDS = tuple(str(i) for i in range(4))
 SHARD_STEAL_REASONS = ("split", "merge", "steal", "rebalance")
+# Scheduler._phase names, seeded on phase_self_seconds: the root
+# schedule_pending, the drain phases in loop order, the informer handlers
+LOOP_PHASES = ("schedule_pending", "queue_drain", "batch_build",
+               "host_build", "host_snapshot", "host_tensorize",
+               "host_group_seed", "host_cache", "audit", "device_dispatch",
+               "cluster_probe", "readback", "commit", "bookkeeping",
+               "release", "dispatcher_flush", "pod_add", "pod_update",
+               "pod_delete")
+# background threads whose CPU time thread_cpu_seconds counts
+SIDE_THREADS = ("audit", "host_profiler")
 CROSS_SHARD_OUTCOMES = ("conflict", "fenced")
 
 
@@ -422,12 +432,39 @@ class SchedulerMetrics:
             "or a dirty-row set too large for the incremental scatter)."))
         self.drain_phase = r.register(Histogram(
             n + "drain_phase_seconds",
-            "Per-drain wall time by phase: host_build (snapshot + batch "
-            "+ group seeding), device (dispatch + readback wait), commit "
+            "Per-drain wall time by phase, each inclusive of the phases "
+            "nested in it: host_build (snapshot + batch + group seeding), "
+            "device (the dispatch enqueue only), readback (waiting for "
+            "the device results, plus inexact-run replays), commit "
             "(assume + bind enqueue + failure handling). host_build "
             "decomposes into host_snapshot / host_tensorize / "
             "host_group_seed / host_cache children.",
             label_names=("phase",)))
+        # loop phases on the profiler's clock (utils/tracing.py): every
+        # second of the host loop, charged to the innermost open phase
+        self.phase_self_seconds = r.register(Counter(
+            n + "phase_self_seconds_total",
+            "Host-loop seconds by the innermost open phase "
+            "(Scheduler._phase; PhaseTrack self time): each phase net of "
+            "the phases nested in it, so the phases of one "
+            "schedule_pending call sum to its wall and its own "
+            "schedule_pending share is the loop time no named phase "
+            "covers. pod_add / pod_update / pod_delete are the informer "
+            "handlers (upstream scheduler_event_handling_duration_seconds).",
+            ("phase",)))
+        self.gc_pause_seconds = r.register(Counter(
+            n + "gc_pause_seconds_total",
+            "Seconds the Python garbage collector ran, by generation "
+            "(gc.callbacks, process-wide; mirrored at exposition). The "
+            "collecting thread holds the interpreter lock throughout.",
+            ("generation",)))
+        self.thread_cpu_seconds = r.register(Counter(
+            n + "thread_cpu_seconds_total",
+            "CPU seconds of the scheduler's own background work, by "
+            "thread: audit (shadow-oracle replays), host_profiler (stack "
+            "samples). They compete with the host loop for the "
+            "interpreter lock.",
+            ("thread",)))
         self.events_total = r.register(Counter(
             n + "events_total",
             "Scheduling events emitted by the event recorder, by type "
@@ -673,10 +710,17 @@ class SchedulerMetrics:
         self.wave_placement_waves.inc(by=0)
         self.wave_conflict_ratio.seed()
         self.wave_accepted_prefix.seed()
-        for phase in ("host_build", "device", "commit",
+        for phase in ("host_build", "device", "readback", "commit",
                       "host_snapshot", "host_tensorize",
                       "host_group_seed", "host_cache"):
             self.drain_phase.seed(phase)
+        for phase in LOOP_PHASES:
+            self.phase_self_seconds.inc(phase, by=0)
+        from ..utils.tracing import GC_PAUSES
+        for gen in range(len(GC_PAUSES.seconds)):
+            self.gc_pause_seconds.inc(str(gen), by=0)
+        for thread in SIDE_THREADS:
+            self.thread_cpu_seconds.inc(thread, by=0)
         # remaining registered-but-unseeded series: dashboards and
         # bench_metrics.prom must carry every series even when the run
         # never observes them (no permit waits, no divergence, no events)
@@ -803,7 +847,15 @@ class SchedulerMetrics:
         if ratio is not None:
             self.shard_imbalance_ratio.set(float(ratio))
 
+    def sync_gc_pauses(self) -> None:
+        """Mirror the process-global collector hook (utils/tracing.py
+        GC_PAUSES) into gc_pause_seconds — absolute, like the ledger."""
+        from ..utils.tracing import GC_PAUSES
+        for gen, secs in enumerate(GC_PAUSES.seconds):
+            self.gc_pause_seconds._values[(str(gen),)] = secs
+
     def exposition(self) -> str:
         self.sync_compile_ledger()
         self.sync_observatory()
+        self.sync_gc_pauses()
         return self.registry.exposition()

@@ -47,6 +47,8 @@ import time as _time
 from dataclasses import dataclass, field
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from ..framework.interface import CycleState
 from ..framework.types import Diagnosis, FitError, PodInfo
 
@@ -628,6 +630,19 @@ class ShadowOracleAudit:
             _time.sleep(0.01)
 
     def _process(self, rec: AuditRecord) -> None:
+        """Replay + diff one record, as an `audit_replay` profiler span on
+        the thread that runs it; its CPU seconds count under
+        scheduler_thread_cpu_seconds_total{thread="audit"}."""
+        cpu0 = _time.thread_time()
+        try:
+            with TraceAnnotation("audit_replay"):
+                self._replay(rec)
+        finally:
+            if self.metrics is not None:
+                self.metrics.thread_cpu_seconds.inc(
+                    "audit", by=_time.thread_time() - cpu0)
+
+    def _replay(self, rec: AuditRecord) -> None:
         t0 = _time.perf_counter()
         try:
             # replay over fresh clones: rec.nodes is the LEDGERED capture

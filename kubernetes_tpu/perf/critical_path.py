@@ -54,7 +54,7 @@ from __future__ import annotations
 #   backpressure   streaming-pipeline stall seconds (a depth cap held the
 #                  drain back); structurally zero in lock-step operation
 #   idle           host blocked on the device readback with no overlap
-#                  (device_wait) — the seconds the pipeline exists to
+#                  (readback) — the seconds the pipeline exists to
 #                  reclaim
 CAUSES = ("host_build", "device_compute", "device_comms", "commit",
           "backpressure", "idle")
@@ -71,7 +71,7 @@ def attribute_drain(phases: dict, kernels: dict = None,
     """One drain's segments → {"verdict", "causes", "chain"}.
 
     `phases` is the FlightRecord/_PendingDrain phase dict (host_build,
-    device_dispatch, device_wait, commit + the host sub-phases);
+    device_dispatch, readback, commit + the host sub-phases);
     `kernels` the per-kernel device-lane seconds; `comms_share` the
     sharded-lane profile's collective share of the device window (0.0
     unsharded); `backpressure_s` the pipeline stall seconds attributed
@@ -87,7 +87,7 @@ def attribute_drain(phases: dict, kernels: dict = None,
         "device_comms": device_s * share,
         "commit": max(float(phases.get("commit", 0.0)), 0.0),
         "backpressure": max(float(backpressure_s), 0.0),
-        "idle": max(float(phases.get("device_wait", 0.0)), 0.0),
+        "idle": max(float(phases.get("readback", 0.0)), 0.0),
     }
     verdict = max(CAUSES, key=lambda c: (causes[c], -CAUSES.index(c)))
     if causes[verdict] <= 0.0:
@@ -127,7 +127,7 @@ def _chain(phases: dict, kernels: dict, causes: dict) -> list:
         float(phases.get("device_dispatch", 0.0)) - lane_total, dev_cause)
     seg("backpressure_stall", causes.get("backpressure", 0.0),
         "backpressure")
-    seg("device_wait", float(phases.get("device_wait", 0.0)), "idle")
+    seg("readback", float(phases.get("readback", 0.0)), "idle")
     seg("commit", float(phases.get("commit", 0.0)), "commit")
     return chain
 

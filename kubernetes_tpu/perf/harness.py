@@ -485,7 +485,9 @@ class WorkloadRunner:
         m = sched.metrics
         extras = {
             "host_build_s": round(m.drain_phase.sum("host_build"), 3),
-            "device_s": round(m.drain_phase.sum("device"), 3),
+            # the dispatch and the wait for its results
+            "device_s": round(m.drain_phase.sum("device")
+                              + m.drain_phase.sum("readback"), 3),
             "commit_s": round(m.drain_phase.sum("commit"), 3),
             # host_build decomposition (this PR's observability layer)
             "host_snapshot_s": round(m.drain_phase.sum("host_snapshot"), 3),

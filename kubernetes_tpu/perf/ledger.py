@@ -42,6 +42,8 @@ import threading
 import time as _time
 from dataclasses import dataclass, field
 
+from jax.profiler import TraceAnnotation
+
 
 @dataclass
 class KernelRecord:
@@ -143,9 +145,11 @@ class CompileLedger:
         survives the call, the donation was ignored and the dispatch paid
         a copy of the resident node state — counted as a miss."""
         before = self._cache_size(fn)
-        t0 = _time.perf_counter()
-        out = fn(*args, **kw)
-        dt = _time.perf_counter() - t0
+        # names the device ops that follow in a jax.profiler trace
+        with TraceAnnotation(f"dispatch:{kernel}"):
+            t0 = _time.perf_counter()
+            out = fn(*args, **kw)
+            dt = _time.perf_counter() - t0
         delta = 0
         if before >= 0:
             delta = self._cache_size(fn) - before

@@ -15,12 +15,13 @@ substrate to read. This module is that substrate:
   bounded streaming histograms keyed `(kernel, shape-sig)`; compiling
   calls stay out of the run histograms (their wall is trace+compile —
   the split the ledger's `runSeconds` bugfix records).
-- a per-drain device-lane capture (`begin_drain`/`end_drain`, thread
+- a per-drain dispatch capture (`begin_drain`/`end_drain`, thread
   local so the standby scheduler and audit worker don't interleave):
-  the scheduler brackets its `device_dispatch` span with it, stamps the
-  per-kernel seconds into the FlightRecorder, and attaches the events
-  as `lane="device"` child spans so the Chrome-trace export shows one
-  host+device timeline (utils/tracing.py gives them their own track).
+  the scheduler brackets its `device_dispatch` phase with it and stamps
+  the per-kernel seconds into the FlightRecorder. These are dispatch
+  walls (the enqueue), not device time: device time is in the
+  `jax.profiler` trace, where `measured_call`'s `dispatch:<kernel>`
+  annotations name the device ops that follow each dispatch.
 - the sharded-lane profile (parallel/sharding.py `profile_shard_lanes`)
   parks its latest result here; /debug/kernels and the
   `scheduler_shard_*` metric families read it back.
@@ -239,8 +240,7 @@ class KernelObservatory:
     def on_call(self, kernel: str, start: float, seconds: float,
                 compiled: bool, args: tuple) -> None:
         """One dispatch, reported by `CompileLedger.measured_call`.
-        `start` is the perf_counter at call entry (the tracer's clock, so
-        lane events nest inside the drain's device span)."""
+        `start` is the perf_counter at call entry."""
         if not self._enabled:
             return
         key = _shape_key(args)
@@ -275,7 +275,7 @@ class KernelObservatory:
             return
         self.costs.record_compile(kernel, fn, args, kw)
 
-    # -- per-drain device lane ------------------------------------------------
+    # -- per-drain dispatch capture ------------------------------------------
 
     def begin_drain(self) -> None:
         """Open the calling thread's dispatch capture window (the
@@ -304,21 +304,6 @@ class KernelObservatory:
         for kernel, _start, seconds, _compiled in events:
             out[kernel] = out.get(kernel, 0.0) + seconds
         return {k: round(v, 6) for k, v in out.items()}
-
-    @staticmethod
-    def lane_spans(events: list, drain_id: int = 0) -> list:
-        """Capture events → `lane="device"` child Spans for the tracer's
-        device_dispatch span (utils/tracing.py routes the lane onto its
-        own Chrome-trace track)."""
-        from ..utils.tracing import Span
-        spans = []
-        for kernel, start, seconds, compiled in events:
-            attrs = {"lane": "device", "drain": drain_id}
-            if compiled:
-                attrs["compiled"] = True
-            spans.append(Span(name=f"kernel:{kernel}", start=start,
-                              duration_s=seconds, attributes=attrs))
-        return spans
 
     # -- shard lanes ----------------------------------------------------------
 

@@ -77,8 +77,14 @@ class HostProfiler:
                  bucket_fn: Optional[Callable[[], int]] = None,
                  owner: Optional[object] = None,
                  max_depth: int = 128,
-                 window_s: int = 900):
+                 window_s: int = 900,
+                 thread_cpu=None):
         self.hz = float(hz)
+        # the sampler's own CPU seconds land in this Counter
+        # (scheduler_thread_cpu_seconds_total{thread="host_profiler"}); a
+        # counter, not the metrics object, whose callbacks would hold the
+        # owner alive and keep this thread running
+        self.thread_cpu = thread_cpu
         self.phase_fn = phase_fn
         self.bucket_fn = bucket_fn
         self._owner_ref = weakref.ref(owner) if owner is not None else None
@@ -131,10 +137,14 @@ class HostProfiler:
         while not self._stop.wait(interval):
             if self._owner_ref is not None and self._owner_ref() is None:
                 break   # owner collected: nothing left to profile
+            cpu0 = _time.thread_time()
             try:
                 self.sample_once()
             except Exception:   # pragma: no cover - sampling must not die
                 self.dropped += 1
+            if self.thread_cpu is not None:
+                self.thread_cpu.inc("host_profiler",
+                                    by=_time.thread_time() - cpu0)
 
     # -- sampling -------------------------------------------------------------
 

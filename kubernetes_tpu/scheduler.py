@@ -507,14 +507,18 @@ class Scheduler:
         # jax.profiler session directory (config profilerTraceDir; "" = off)
         self.profiler_trace_dir = (
             config.profiler_trace_dir if config is not None else "")
+        # the open loop phase (Scheduler._phase): self time per phase into
+        # scheduler_phase_self_seconds_total, and the tag of every host
+        # profiler sample; the collector's pauses are counted beside it
+        from .utils.tracing import GC_PAUSES, PhaseTrack
+        self.phase_track = PhaseTrack(self.metrics.phase_self_seconds)
+        GC_PAUSES.install()
         # continuous host profiling (perf/profiler.py): a sampling thread
         # follows the host-loop thread, tagging every stack sample with
-        # the open drain phase (PhaseTrack, pushed in lockstep with the
-        # tracer spans) and the dispatching drain's signature-cardinality
-        # bucket. The thread starts lazily on the first schedule call and
-        # exits when this Scheduler is collected (weakref owner).
-        from .utils.tracing import PhaseTrack
-        self.phase_track = PhaseTrack()
+        # the open loop phase and the dispatching drain's signature-
+        # cardinality bucket. The thread starts lazily on the first
+        # schedule call and exits when this Scheduler is collected
+        # (weakref owner).
         self._drain_seq = 0          # monotonic drain id (drain_id=0: none)
         self._sig_bucket_cell = [0]  # profiler-visible drain sig count
         self.profiler = None
@@ -528,7 +532,7 @@ class Scheduler:
                 hz=self.host_profiler_hz,
                 phase_fn=self.phase_track.current,
                 bucket_fn=(lambda c=cell: c[0]),
-                owner=self)
+                owner=self, thread_cpu=self.metrics.thread_cpu_seconds)
         # runtime sanitizer rails (analysis/rails.py): like the compile
         # ledger, the instance is process-global (the jit caches and the
         # transfer-guard config it drives are process-global) — the gate
@@ -962,6 +966,10 @@ class Scheduler:
         chunk — a gang arriving complete in one chunk then passes
         PreEnqueue quorum at its own add (no gate → un-gate churn at
         all), and the quorum retry runs once per gang, not per member."""
+        with self._phase("pod_add", pods=len(pods)):
+            self._add_pods(pods)
+
+    def _add_pods(self, pods: list[Pod]) -> None:
         plain: list[Pod] = []
         gang_pods: list[Pod] = []
         parked: list[Pod] = []
@@ -1056,6 +1064,10 @@ class Scheduler:
         queueing hints need the individual pod, in-flight event logging,
         pending bind errors), falls back to `_on_pod_update` per pod —
         semantics stay identical by construction."""
+        with self._phase("pod_update", pods=len(pairs)):
+            self._update_pods(pairs)
+
+    def _update_pods(self, pairs: list) -> None:
         q = self.queue
         if (q.unschedulable_pods or q.in_flight_pods
                 or self._bind_errors or self._waiting_pods):
@@ -1085,6 +1097,11 @@ class Scheduler:
             # move_all calls are no-ops — elided wholesale
 
     def _on_pod_delete(self, pod: Pod) -> None:
+        # one per pod: the self-time mark alone, no span
+        with self.phase_track.scope("pod_delete"):
+            self._delete_pod(pod)
+
+    def _delete_pod(self, pod: Pod) -> None:
         self.workload_manager.delete_pod(pod)
         if pod.uid in self._waiting_pods:
             self._reject_waiting(pod.uid, "pod deleted")
@@ -1291,12 +1308,17 @@ class Scheduler:
         counted). With `wait=False` the call returns after dispatching:
         device results still in flight commit on a later call (or
         `wait_pending()`), which is what lets ingestion of the next pod
-        chunk overlap the device readback."""
+        chunk overlap the device readback. The call is the root loop
+        phase: its own self time is the loop time no named phase covers."""
         if self.ha_role == "standby":
             # a standby never writes: binds from a non-leader would race
             # the active scheduler (and be fenced anyway). Takeover calls
             # promote() before resuming the loop.
             return 0
+        with self._phase("schedule_pending"):
+            return self._schedule_pending(max_batches, wait)
+
+    def _schedule_pending(self, max_batches: int, wait: bool) -> int:
         if self.profiler is not None:
             self.profiler.ensure_running()
         start = self.scheduled_count
@@ -1304,7 +1326,8 @@ class Scheduler:
         while True:
             # commit whatever has already landed
             self.commit_ready()
-            self.queue.flush_backoff_completed()
+            with self._phase("queue_drain"):
+                self.queue.flush_backoff_completed()
             if not len(self.queue.active_q):
                 if not wait or not self._pending:
                     break
@@ -1325,7 +1348,8 @@ class Scheduler:
             # device shapes are drain-size independent (uniform L comes
             # from batch_size, scan buckets from pow2 padding), so take
             # everything up to the cap — one execution per drain
-            qpis = self.queue.drain(self.batch_size)
+            with self._phase("queue_drain"):
+                qpis = self.queue.drain(self.batch_size)
             if not qpis:
                 break
             with self.tracer.span("scheduling_cycle",
@@ -1335,7 +1359,7 @@ class Scheduler:
                     self._schedule_batch(qpis)
                 while len(self._pending) > self.max_inflight_drains:
                     self._commit_next()
-                with self.tracer.span("dispatcher_flush"):
+                with self._phase("dispatcher_flush"):
                     self.dispatcher.flush()
                 cycle.set(bound=self.scheduled_count - before)
             batches += 1
@@ -1344,7 +1368,8 @@ class Scheduler:
         if wait:
             self.wait_pending()
         elif len(self.dispatcher):
-            self.dispatcher.flush()
+            with self._phase("dispatcher_flush"):
+                self.dispatcher.flush()
         if (self._shard_profile_args is not None
                 and not self._shard_profile_done
                 and self.shard_profile_auto
@@ -1418,7 +1443,8 @@ class Scheduler:
         """Commit every in-flight drain and flush the dispatcher — the
         pipeline barrier (reference WaitForCacheSync-style quiescence)."""
         self._drain_pending()
-        self.dispatcher.flush()
+        with self._phase("dispatcher_flush"):
+            self.dispatcher.flush()
 
     def prime(self) -> None:
         """Pre-build the host snapshot and device staging arrays from the
@@ -1481,7 +1507,8 @@ class Scheduler:
         if not qpis:
             return bound
         pods = [q.pod for q in qpis]
-        batch = self.builder.build(pods, pad_to=self.batch_size)
+        with self._phase("batch_build", pods=len(pods)):
+            batch = self.builder.build(pods, pad_to=self.batch_size)
         if not batch.host_fallback.any():
             # common case: whole drain is device-eligible; reuse this build
             return bound + self._dispatch_device_drain(qpis, profile,
@@ -1571,7 +1598,6 @@ class Scheduler:
         from .ops.groups import scatter_new_rows, to_device
         from .utils.logging import log_context
 
-        t_entry = _time.perf_counter()
         did = self._drain_seq = self._drain_seq + 1
         if not self._device_available():
             # circuit breaker open: the device tier is sidelined until the
@@ -1597,10 +1623,10 @@ class Scheduler:
 
         with log_context(drain=did):
             return self._dispatch_device_drain_inner(qpis, profile, prebuilt,
-                                                     t_entry, did, gang)
+                                                     did, gang)
 
-    def _dispatch_device_drain_inner(self, qpis, profile, prebuilt,
-                                     t_entry, did, gang=None):
+    def _dispatch_device_drain_inner(self, qpis, profile, prebuilt, did,
+                                     gang=None):
         from .ops.groups import scatter_new_rows, to_device
 
         ph: dict[str, float] = {}
@@ -1615,12 +1641,12 @@ class Scheduler:
                       and (gang is None or self.gang_contiguity_weight == 0)
                       and self.audit.want())
         audit_rec = None
-        if audit_want:
-            with self.tracer.span("audit_quiesce", drain=did):
-                self._drain_pending()
-                self.cache.update_snapshot(self.snapshot)
-        with self.tracer.span("host_build", pods=len(qpis), drain=did), \
-                self.phase_track.scope("host_build"):
+        with self._phase("host_build", ph, pods=len(qpis), drain=did):
+            if audit_want:
+                # the commits of the quiesce are phases of their own
+                with self._phase("audit", drain=did):
+                    self._drain_pending()
+                    self.cache.update_snapshot(self.snapshot)
             carry = self._device_carry
             nominator = self.queue.nominator
             ovl_fp = nominator.version if nominator.nominated_pods else -1
@@ -1666,7 +1692,7 @@ class Scheduler:
                 # clone + fingerprint + hash-chain append: the snapshot
                 # was refreshed at the quiesce above and nothing between
                 # there and here mutates the cache
-                with self.tracer.span("audit_capture", drain=did):
+                with self._phase("audit", drain=did):
                     audit_rec = self.audit.capture(
                         did, profile, qpis, self.snapshot, segment_batch,
                         len(qpis), self.state, self.builder,
@@ -1820,12 +1846,8 @@ class Scheduler:
                         # the overlay two-pass is outside the gang program
                         self.metrics.gang_dispatch.inc("fallback")
                         gang = None
-        t0 = _time.perf_counter()
-        self.metrics.drain_phase.observe(max(t0 - t_entry, 0.0),
-                                         "host_build")
         for name, dt in ph.items():
             self.metrics.drain_phase.observe(dt, name)
-        ph["host_build"] = t0 - t_entry
         if self.profiler is not None:
             # profiler tag: this drain's distinct-signature count (pow2
             # bucketed by the profiler) — host cost per cardinality regime
@@ -1834,23 +1856,24 @@ class Scheduler:
         if audit_rec is not None:
             # keep the PRE-dispatch device inputs (carry copied on
             # device) so /debug/explain can replay any pod's exact step
-            self.audit.attach_device(
-                audit_rec, profile.score_config, na, carry, table,
-                segment_batch, n, self._gd_dev, self._gd_fam,
-                names=self.state.node_names)
+            with self._phase("audit", drain=did):
+                self.audit.attach_device(
+                    audit_rec, profile.score_config, na, carry, table,
+                    segment_batch, n, self._gd_dev, self._gd_fam,
+                    names=self.state.node_names)
+        t0 = _time.perf_counter()
         try:
             # kernel observatory: capture every measured_call dispatched
-            # inside the device span as a device-lane event — the span's
-            # wall decomposes into named kernel dispatches
+            # inside the device phase — its wall decomposes into named
+            # kernel dispatches
             self.observatory.begin_drain()
-            with self.tracer.span("device_dispatch", pods=n,
-                                  groups=groups_needed, drain=did,
-                                  batch_bucket=len(segment_batch.valid)) as ds:
+            with self._phase("device_dispatch", ph, pods=n,
+                             groups=groups_needed, drain=did,
+                             batch_bucket=len(segment_batch.valid)) as ds:
                 # rails: the dispatch region must consume only
                 # device-resident (or explicitly staged) inputs — implicit
                 # transfers raise here with the SanitizerRails gate on
-                with self.phase_track.scope("device"), \
-                        self.rails.guard_dispatch():
+                with self.rails.guard_dispatch():
                     carry, records = self._dispatch_runs(
                         profile, na, carry, segment_batch, table, n,
                         groups_needed, ovl=ovl, nom=nom,
@@ -1882,18 +1905,13 @@ class Scheduler:
                 self.metrics.gang_dispatch.inc("fallback")
             self._drain_pending()
             return sum(1 if self._schedule_one_host(q) else 0 for q in qpis)
-        ph["device_dispatch"] = _time.perf_counter() - t0
-        self.metrics.drain_phase.observe(
-            max(_time.perf_counter() - t0, 0.0), "device")
-        # close the device-lane capture: per-kernel seconds ride the
-        # flight record, and the events become lane="device" child spans
-        # of the dispatch span (one host+device Chrome-trace timeline)
-        lane_events = self.observatory.end_drain()
-        kernels = self.observatory.lane_seconds(lane_events)
-        if lane_events and hasattr(ds, "children"):
-            ds.children.extend(
-                self.observatory.lane_spans(lane_events, drain_id=did))
-        self._device_carry = carry
+        self.metrics.drain_phase.observe(ph["device_dispatch"], "device")
+        # close the capture: per-kernel dispatch seconds ride the flight
+        # record
+        kernels = self.observatory.lane_seconds(self.observatory.end_drain())
+        # installing the drain's carry drops the previous one
+        with self._phase("release"):
+            self._device_carry = carry
         self.device_batches += 1
         self.metrics.device_batch_size.observe(n)
         probe = None
@@ -1902,7 +1920,7 @@ class Scheduler:
             # input (na, carry, dom) is already device-resident, so the
             # probe costs zero extra h2d; the result rides the drain's
             # async copy window and resolves at commit
-            with self.tracer.span("cluster_probe", drain=did):
+            with self._phase("cluster_probe", drain=did):
                 dom = self._gang_domains(na, need=True)
                 if self.mesh is not None:
                     # the mesh twin: feeding node-sharded inputs to the
@@ -1924,21 +1942,25 @@ class Scheduler:
         return 0
 
     @contextmanager
-    def _phase(self, name: str, ph: dict, **attrs):
-        """Time one host-build sub-phase: tracer child span, an entry in
-        `ph` (flight recorder + drain_phase sub-phase series), and the
-        PhaseTrack mark the sampling profiler attributes against."""
+    def _phase(self, name: str, ph: Optional[dict] = None, **attrs):
+        """Time one phase of the loop, the one way a phase is timed: the
+        PhaseTrack mark (self time into scheduler_phase_self_seconds_total,
+        the sampling profiler's tag), a tracer phase span (a profiler
+        annotation on the device ops' clock) and, when `ph` is given, the
+        inclusive seconds under `name` (flight record, drain_phase)."""
         t0 = _time.perf_counter()
         self.phase_track.push(name)
         try:
             # rails.declared opens a transfer-guard allow window for the
             # host phases whose uploads are part of the drain contract
             # (no-op with the SanitizerRails gate off)
-            with self.tracer.span(name, **attrs), self.rails.declared(name):
-                yield
+            with self.tracer.phase(name, **attrs) as sp, \
+                    self.rails.declared(name):
+                yield sp
         finally:
             self.phase_track.pop()
-            ph[name] = ph.get(name, 0.0) + (_time.perf_counter() - t0)
+            if ph is not None:
+                ph[name] = ph.get(name, 0.0) + (_time.perf_counter() - t0)
 
     def _nominated_rows(self, qpis: list[QueuedPodInfo]):
         """i32 [n] row index of each drain pod's OWN nomination (-1 =
@@ -2695,19 +2717,21 @@ class Scheduler:
         drains — against the corrected chain."""
         pd = self._pending.popleft()
         out = np.full((pd.n,), -1, np.int32)
-        t0 = _time.perf_counter()
+        names = self.state.node_names
         try:
-            with self.phase_track.scope("device"):
+            # the wait for the device results (zero when the async copy
+            # already landed), any inexact-run replay, and the range check
+            with self._phase("readback", pd.phases):
                 self._resolve_records(pd, out)
+                assigned = out[out >= 0]
+                invalid = ((out < -1).any() or (out >= len(names)).any()
+                           or any(not names[int(a)] for a in assigned))
         except Exception as e:
             # XLA fault surfacing at readback/replay: degrade this drain
             # (and the chained later ones) to the host oracle
             self._device_fault_abort(pd, "commit", e)
             return
-        names = self.state.node_names
-        assigned = out[out >= 0]
-        if ((out < -1).any() or (out >= len(names)).any()
-                or any(not names[int(a)] for a in assigned)):
+        if invalid:
             # a garbage assignment tensor (the argmax of a non-finite
             # score column lands here) must never reach the cache
             self._device_fault_abort(pd, "invalid_assignment", ValueError(
@@ -2719,13 +2743,15 @@ class Scheduler:
             self._test_assignment_perturb(pd, out)
         if pd.records:
             self._record_device_success()
-            # readback wait (zero when the async copy already landed)
-            wait = max(_time.perf_counter() - t0, 0.0)
-            pd.phases["device_wait"] = wait
-            self.metrics.drain_phase.observe(wait, "device")
+            self.metrics.drain_phase.observe(pd.phases["readback"],
+                                             "readback")
         self.metrics.device_batch_duration.observe(
             max(_time.perf_counter() - pd.dispatched_at, 0.0))
         self._commit_assignments(pd, out)
+        # the committed drain's last references: its host and device
+        # buffers are freed here
+        with self._phase("release"):
+            del pd, out, assigned
 
     def _resolve_records(self, pd: "_PendingDrain", out) -> None:
         """Resolve a drain's device results into `out`, replaying inexact
@@ -2847,13 +2873,27 @@ class Scheduler:
         """Host commit of a resolved drain: bulk assume + bind enqueue for
         hook-free pods, the full reserve/permit/pre-bind chain for the
         rest, failure handling for the unassigned. Runs under the drain's
-        id (log context + event tagging) and the `commit` phase mark."""
+        id (log context + event tagging); the `commit` phase, then the
+        `bookkeeping` phase of the drain's telemetry."""
         from .utils.logging import log_context
         self.events.current_drain = pd.drain_id
+        # attempt latency = dispatch→commit wall time split over the drain.
+        # NOTE: with the async pipeline this includes time the result sat
+        # in flight behind other work — an SLI-style number (queue-to-bind),
+        # deliberately not a device-busy-time measurement.
+        per_pod = (max(_time.perf_counter() - pd.dispatched_at, 0.0)
+                   / max(pd.n, 1))
         try:
-            with log_context(drain=pd.drain_id), \
-                    self.phase_track.scope("commit"):
-                return self._commit_assignments_inner(pd, out)
+            with log_context(drain=pd.drain_id):
+                with self._phase("commit", pd.phases, drain=pd.drain_id):
+                    bound, failures, fail_msgs = self._commit_decisions(
+                        pd, out, per_pod)
+                self.metrics.drain_phase.observe(pd.phases["commit"],
+                                                 "commit")
+                with self._phase("bookkeeping", drain=pd.drain_id):
+                    self._commit_bookkeeping(pd, out, per_pod, bound,
+                                             failures, fail_msgs)
+                return bound
         finally:
             self.events.current_drain = 0
 
@@ -2896,8 +2936,10 @@ class Scheduler:
         m.bottleneck_drains.inc(cp["verdict"])
         return cp
 
-    def _commit_assignments_inner(self, pd: _PendingDrain, out) -> int:
-        t_commit = _time.perf_counter()
+    def _commit_decisions(self, pd: _PendingDrain, out, per_pod: float):
+        """Assume + bind enqueue for the bound pods, failure handling for
+        the rest. Returns (bound, failed qpis, FailedScheduling messages
+        for the audit)."""
         qpis = pd.qpis
         profile = pd.profile
         fwk = profile.framework
@@ -2905,11 +2947,6 @@ class Scheduler:
         self.schedule_attempts += n
         from .metrics import SCHEDULED, UNSCHEDULABLE
         n_ok = int((out >= 0).sum())
-        # attempt latency = dispatch→commit wall time split over the drain.
-        # NOTE: with the async pipeline this includes time the result sat
-        # in flight behind other work — an SLI-style number (queue-to-bind),
-        # deliberately not a device-busy-time measurement.
-        per_pod = max(_time.perf_counter() - pd.dispatched_at, 0.0) / max(n, 1)
         if n_ok:
             self.metrics.attempt_duration.observe(per_pod, SCHEDULED,
                                                   profile.name)
@@ -2971,9 +3008,17 @@ class Scheduler:
                         # against the oracle replay's
                         fail_msgs[qpi.pod.uid] = str(err)
                     self._handle_failure(qpi, err)
-        commit_s = max(_time.perf_counter() - t_commit, 0.0)
-        self.metrics.drain_phase.observe(commit_s, "commit")
-        pd.phases["commit"] = pd.phases.get("commit", 0.0) + commit_s
+        return bound, failures, fail_msgs
+
+    def _commit_bookkeeping(self, pd: _PendingDrain, out, per_pod: float,
+                            bound: int, failures: list,
+                            fail_msgs: dict) -> None:
+        """The drain's telemetry after its commit: SLO feeds, journey and
+        timeline, the cluster-probe sample, flight record, critical path,
+        the audit hand-off and the log line."""
+        qpis = pd.qpis
+        profile = pd.profile
+        n = pd.n
         # SLO engine feeds (obs/slo.py): attempt latency, queue→bind e2e
         # and the device-tier health, one observation batch per drain
         slo = self.slo
@@ -3043,7 +3088,6 @@ class Scheduler:
                 klog.v(5).info("unschedulable", pod=qpi.pod.uid,
                                plugins=sorted(qpi.unschedulable_plugins
                                               or ()))
-        return bound
 
     def _fail_rejected_gang(self, pd: _PendingDrain, qpis: list,
                             diag_cache: dict) -> None:

@@ -1,38 +1,50 @@
-"""Tracing: span trees + slow-cycle logging.
+"""Tracing: span trees, phase self time, profiler annotations.
 
 The reference wires OpenTelemetry through component-base/tracing (spans
 around the scheduling cycle, schedule_one.go) and logs slow cycles via
-klog verbosity. This is the dependency-free analog:
+klog verbosity. This is the dependency-free analog, on the profiler's
+clock:
 
 - `Tracer.span(name)` context manager builds a per-cycle span tree with
-  wall-clock durations and optional attributes.
+  wall-clock durations and optional attributes, and enters
+  `jax.profiler.TraceAnnotation(name)` for the span's extent: inside a
+  `jax.profiler` session every span lands on the trace's host plane, on
+  the same clock as the device's ops (outside a session the annotation is
+  a no-op TraceMe, well under a microsecond). `Tracer.phase(name)` is a
+  child span of the open cycle, or only the annotation between cycles,
+  so the scheduler's loop phases never become cycles of their own.
 - finished root spans whose duration exceeds `slow_threshold_s` are kept in
   `slow_cycles` (ring buffer) and handed to `on_slow` (default: stdlib
   logging at WARNING) with a per-child breakdown — the "why was this cycle
   slow" answer the reference gets from attempt-duration histograms plus
   trace sampling.
 - `NOOP_TRACER` keeps the hot path branch-free when tracing is off: span()
-  returns a reusable null context.
+  and phase() return a reusable null context and annotate nothing.
 - finished span trees export as Chrome-trace / Perfetto JSON
   (`to_chrome_trace` / `export_chrome_trace`): monotonic timestamps, one
   complete ("X") event per span, attributes as args — load the file at
   chrome://tracing or ui.perfetto.dev. `keep_recent` retains the last K
   root spans regardless of duration so a bench run can export its whole
-  drain history.
+  drain history. Device time is not in this export: it is in the
+  `jax.profiler` trace, beside the same spans.
 - `jax_profiler_session(dir)` optionally brackets a workload with a
   jax.profiler trace (XLA/TPU-level view under the host spans), gated by
   the `profilerTraceDir` config knob.
-- `PhaseTrack` is the continuous-profiler hook: a plain-list span-name
-  stack the Scheduler pushes/pops in lockstep with its phase spans
-  (host_snapshot/host_tensorize/host_group_seed/host_cache/device/
-  commit), readable from ANY thread — the sampling host profiler
-  (perf/profiler.py) tags every sample with `current()`. Kept separate
-  from Tracer so attribution works even under NOOP_TRACER (two list ops
-  per phase per drain — cheap enough to never turn off).
+- `PhaseTrack` is the stack of open loop phases the Scheduler pushes and
+  pops (Scheduler._phase), readable from ANY thread — the sampling host
+  profiler (perf/profiler.py) tags every sample with `current()`. It also
+  charges each elapsed stretch to the phase on top of the stack, so each
+  phase's seconds are net of the phases nested in it and all of them sum
+  to the wall (`scheduler_phase_self_seconds_total`). Kept separate from
+  Tracer so it works under NOOP_TRACER too.
+- `GC_PAUSES` is a `gc.callbacks` hook: collector seconds per generation
+  (`scheduler_gc_pause_seconds_total`) and a `gc` annotation from each
+  collection's start to its stop, on the collecting thread.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import logging
 import time
@@ -40,6 +52,8 @@ from collections import deque
 from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Callable, Optional
+
+from jax.profiler import TraceAnnotation
 
 logger = logging.getLogger("kubernetes_tpu.tracing")
 
@@ -80,35 +94,65 @@ class Span:
 
 
 class PhaseTrack:
-    """Cross-thread-readable stack of open phase/span names.
+    """Cross-thread-readable stack of open phase names, with self time.
 
-    The owner (single-threaded host loop) pushes and pops; the profiler
-    thread only reads the top — CPython list append/pop/index are atomic
-    under the GIL, so no lock is needed and a torn read is impossible."""
+    The owner (the host loop; one thread at a time) pushes and pops; the
+    profiler thread only reads the top — CPython list append/pop/index are
+    atomic under the GIL, so no lock is needed and a torn read is
+    impossible. Every push and pop charges the time since the previous one
+    to the phase that was on top (`sink.inc(name, by=seconds)`, a metrics
+    Counter), so a phase's charge excludes the phases nested in it and the
+    charges of one outermost phase sum to its wall. Time with the stack
+    empty is charged to no phase."""
 
-    __slots__ = ("_stack",)
+    __slots__ = ("_stack", "_sink", "_clock", "_mark")
 
-    def __init__(self) -> None:
+    def __init__(self, sink=None,
+                 clock: Callable[[], float] = time.perf_counter) -> None:
         self._stack: list = []
+        self._sink = sink
+        self._clock = clock
+        self._mark = 0.0
+
+    def _charge(self) -> None:
+        now = self._clock()
+        if self._stack and self._sink is not None:
+            self._sink.inc(self._stack[-1], by=now - self._mark)
+        self._mark = now
 
     def push(self, name: str) -> None:
+        self._charge()
         self._stack.append(name)
 
     def pop(self) -> None:
         if self._stack:
+            self._charge()
             self._stack.pop()
 
     def current(self) -> str:
         s = self._stack
         return s[-1] if s else ""
 
-    @contextmanager
-    def scope(self, name: str):
-        self._stack.append(name)
-        try:
-            yield
-        finally:
-            self.pop()
+    def scope(self, name: str) -> "_PhaseScope":
+        return _PhaseScope(self, name)
+
+
+class _PhaseScope:
+    """`with track.scope(name):` — a class, not a generator, since the
+    scheduler opens one per deleted pod."""
+
+    __slots__ = ("_track", "_name")
+
+    def __init__(self, track: PhaseTrack, name: str) -> None:
+        self._track = track
+        self._name = name
+
+    def __enter__(self) -> None:
+        self._track.push(self._name)
+
+    def __exit__(self, *exc) -> bool:
+        self._track.pop()
+        return False
 
 
 class _NullSpan:
@@ -150,7 +194,8 @@ class Tracer:
             parent.children.append(sp)
         self._stack.append(sp)
         try:
-            yield sp
+            with TraceAnnotation(name):
+                yield sp
         finally:
             self._stack.pop()
             sp.duration_s = self.clock() - sp.start
@@ -160,6 +205,17 @@ class Tracer:
                 if sp.duration_s >= self.slow_threshold_s:
                     self.slow_cycles.append(sp)
                     self.on_slow(sp)
+
+    @contextmanager
+    def phase(self, name: str, **attributes):
+        """A child span of the open cycle; between cycles only the profiler
+        annotation (a phase never starts a cycle of its own)."""
+        if self._stack:
+            with self.span(name, **attributes) as sp:
+                yield sp
+        else:
+            with TraceAnnotation(name):
+                yield _NULL_SPAN
 
     def export_chrome_trace(self, path: str) -> int:
         """Write the retained root spans (recent if enabled, else the slow
@@ -184,25 +240,47 @@ class NoopTracer:
     def span(self, name: str, **attributes):
         return _NULL_SPAN
 
+    def phase(self, name: str, **attributes):
+        return _NULL_SPAN
+
 
 NOOP_TRACER = NoopTracer()
+
+
+class GCPauses:
+    """`gc.callbacks` hook (module docstring). Process-global like the
+    collector it observes; `install()` is idempotent. CPython collects
+    under the interpreter lock and never nests collections, so one start
+    mark suffices."""
+
+    def __init__(self) -> None:
+        self.seconds = [0.0, 0.0, 0.0]     # by generation
+        self._t0 = 0.0
+        self._ann: Optional[TraceAnnotation] = None
+
+    def install(self) -> None:
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._ann = TraceAnnotation("gc")
+            self._ann.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._ann is not None:     # not a collection open at install
+            self.seconds[info["generation"]] += time.perf_counter() - self._t0
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+
+GC_PAUSES = GCPauses()
 
 
 # ---------------------------------------------------------------------------
 # Chrome-trace / Perfetto export
 
 
-# tid of the merged device-kernel lane: spans tagged lane="device" (the
-# kernel observatory's per-dispatch events, attached as children of the
-# drain's device_dispatch span) render as their own Perfetto track under
-# the same process, so the host timeline and its device decomposition
-# read as ONE trace
-DEVICE_LANE_TID = 2
-
-
 def _span_events(sp: Span, out: list, pid: int, tid: int) -> None:
-    if sp.attributes.get("lane") == "device":
-        tid = DEVICE_LANE_TID
     out.append({"ph": "X", "cat": "scheduler", "name": sp.name,
                 "ts": sp.start * 1e6,            # µs, monotonic base
                 "dur": max(sp.duration_s, 0.0) * 1e6,
@@ -218,16 +296,12 @@ def to_chrome_trace(spans: list[Span], process_name: str = "kube-scheduler-tpu"
                     ) -> dict:
     """Span trees → Chrome-trace JSON object (trace_event format, loadable
     at chrome://tracing / ui.perfetto.dev). Every span becomes one complete
-    ("X") event; timestamps keep the tracer's monotonic base. Device-lane
-    spans (kernel observatory dispatches) land on their own thread track
-    (DEVICE_LANE_TID) nested timewise inside their drain's device span."""
+    ("X") event; timestamps keep the tracer's monotonic base."""
     events: list[dict] = [
         {"ph": "M", "name": "process_name", "pid": 1, "tid": 1,
          "args": {"name": process_name}},
         {"ph": "M", "name": "thread_name", "pid": 1, "tid": 1,
          "args": {"name": "host-loop"}},
-        {"ph": "M", "name": "thread_name", "pid": 1,
-         "tid": DEVICE_LANE_TID, "args": {"name": "device-lanes"}},
     ]
     for sp in spans:
         _span_events(sp, events, 1, 1)
@@ -244,8 +318,7 @@ def export_chrome_trace(path: str, spans: list[Span]) -> int:
 def fleet_chrome_trace(members) -> dict:
     """Merge N instances' span histories onto ONE Chrome trace: each
     instance becomes its own process track (pid = shard index + 1, named
-    after the instance) with the usual host-loop / device-lanes threads
-    underneath. All tracers share the monotonic clock base (in-process
+    after the instance) with its host-loop thread underneath. All tracers share the monotonic clock base (in-process
     fleet; the cross-process step will need a clock offset per scrape),
     so per-shard tracks line up timewise — a steal renders as the drain
     span ending on one track and the adopter's drain starting on the
@@ -260,8 +333,6 @@ def fleet_chrome_trace(members) -> dict:
              "args": {"name": f"shard:{name}"}},
             {"ph": "M", "name": "thread_name", "pid": pid, "tid": 1,
              "args": {"name": "host-loop"}},
-            {"ph": "M", "name": "thread_name", "pid": pid,
-             "tid": DEVICE_LANE_TID, "args": {"name": "device-lanes"}},
         ])
         keep_recent = getattr(tracer, "keep_recent", 0)
         spans = list(tracer.recent if keep_recent

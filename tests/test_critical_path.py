@@ -135,7 +135,7 @@ def _verdicts(sched):
 class TestAttributeDrain:
     def test_verdict_is_argmax_over_causes(self):
         cp = attribute_drain({"host_build": 2.0, "device_dispatch": 1.0,
-                              "device_wait": 0.25, "commit": 0.5})
+                              "readback": 0.25, "commit": 0.5})
         assert cp["verdict"] == "host_build"
         assert cp["causes"] == {"host_build": 2.0, "device_compute": 1.0,
                                 "device_comms": 0.0, "commit": 0.5,
@@ -157,7 +157,7 @@ class TestAttributeDrain:
     def test_exact_tie_breaks_in_causes_order(self):
         cp = attribute_drain({"host_build": 1.0, "commit": 1.0})
         assert cp["verdict"] == "host_build"
-        cp = attribute_drain({"commit": 1.0, "device_wait": 1.0})
+        cp = attribute_drain({"commit": 1.0, "readback": 1.0})
         assert cp["verdict"] == "commit"
 
     def test_all_zero_record_is_idle(self):
@@ -178,7 +178,7 @@ class TestAttributeDrain:
     def test_chain_segments_and_residuals(self):
         phases = {"host_build": 0.10, "host_snapshot": 0.03,
                   "host_tensorize": 0.05, "device_dispatch": 0.20,
-                  "device_wait": 0.04, "commit": 0.06}
+                  "readback": 0.04, "commit": 0.06}
         kernels = {"run_uniform": 0.12, "run_wave": 0.05}
         cp = attribute_drain(phases, kernels=kernels)
         spans = {seg["span"]: seg for seg in cp["chain"]}
@@ -188,7 +188,7 @@ class TestAttributeDrain:
         # kernel lanes + device_other cover device_dispatch exactly
         assert spans["kernel:run_uniform"]["cause"] == "device_compute"
         assert spans["device_other"]["seconds"] == pytest.approx(0.03)
-        assert spans["device_wait"]["cause"] == "idle"
+        assert spans["readback"]["cause"] == "idle"
         assert spans["commit"]["cause"] == "commit"
         # zero segments are dropped: no host_group_seed / host_cache rows
         assert "host_group_seed" not in spans

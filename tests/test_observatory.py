@@ -10,8 +10,8 @@ Covers the full stack the tentpole ships:
 - /debug/kernels over a live SchedulerServer, including the acceptance
   cross-check that a drain's per-kernel seconds decompose its
   device_dispatch phase wall;
-- the Chrome-trace merge: device-lane child spans land on their own
-  thread track, strictly nested inside their drain's device span;
+- the profiler trace: `dispatch:<kernel>` annotations on the host plane,
+  nested inside their drain's device_dispatch span;
 - sharded-lane profiling on the 8-device test mesh;
 - retrace_budget(0) holding over warm re-runs with the observatory ON;
 - tools/kernel_sweep.py --self-test and tools/check.py observatory_gaps;
@@ -49,8 +49,7 @@ from kubernetes_tpu.perf.observatory import (GLOBAL as OBS,  # noqa: E402
 from kubernetes_tpu.scheduler import Scheduler  # noqa: E402
 from kubernetes_tpu.server import SchedulerServer  # noqa: E402
 from kubernetes_tpu.testing.wrappers import make_node, make_pod  # noqa: E402
-from kubernetes_tpu.utils.tracing import (DEVICE_LANE_TID,  # noqa: E402
-                                          Tracer, to_chrome_trace)
+from kubernetes_tpu.utils.tracing import Tracer  # noqa: E402
 
 
 @pytest.fixture
@@ -67,7 +66,7 @@ def fresh_obs():
 
 def _mk(nodes=24, **kw):
     """Small drainable cluster with a REAL tracer (the scheduler default
-    is NOOP_TRACER, which drops the device-lane child spans)."""
+    is NOOP_TRACER, which annotates no phase)."""
     api = APIServer()
     kw.setdefault("tracer", Tracer(slow_threshold_s=999.0, keep_recent=64))
     sched = Scheduler(api, batch_size=64, **kw)
@@ -261,14 +260,7 @@ class TestObservatoryCapture:
                   ("run_wave", 2.0, 0.125, True)]
         assert OBS.lane_seconds(events) == {"run_uniform": 0.75,
                                             "run_wave": 0.125}
-        spans = OBS.lane_spans(events, drain_id=7)
-        assert [s.name for s in spans] == ["kernel:run_uniform",
-                                           "kernel:run_uniform",
-                                           "kernel:run_wave"]
-        assert all(s.attributes["lane"] == "device" and
-                   s.attributes["drain"] == 7 for s in spans)
-        assert spans[2].attributes.get("compiled") is True
-        assert "compiled" not in spans[0].attributes
+        assert OBS.lane_seconds([]) == {}
 
     def test_shape_keys_split_plan_histograms(self, fresh_obs):
         OBS.on_call("run_batch", 0.0, 0.001, False, (np.zeros((4, 2)), 3))
@@ -376,43 +368,36 @@ class TestDebugKernels:
 
 
 # ---------------------------------------------------------------------------
-# Chrome-trace merge
+# the profiler trace
 
 
 class TestChromeTraceMerge:
-    def test_device_lane_spans_merge_into_trace(self, fresh_obs):
+    def test_device_lane_spans_merge_into_trace(self, fresh_obs, tmp_path):
+        """Each kernel dispatch is a `dispatch:<kernel>` annotation on the
+        profiler's host plane, inside its drain's device_dispatch span —
+        the names that tie the device ops that follow to a kernel."""
+        import glob
+
+        import jax
         api, sched = _mk()
         _feed(api, 48, spread=12)
-        sched.schedule_pending()
-        spans = list(sched.tracer.recent)
-        assert spans, "tracer retained no root spans"
-        trace = to_chrome_trace(spans)
-        json.dumps(trace)  # valid JSON end to end
-
-        events = trace["traceEvents"]
-        names = [e for e in events
-                 if e["ph"] == "M" and e["name"] == "thread_name"
-                 and e["tid"] == DEVICE_LANE_TID]
-        assert names and names[0]["args"]["name"] == "device-lanes"
-
-        lanes = [e for e in events
-                 if e["ph"] == "X" and e["tid"] == DEVICE_LANE_TID]
-        assert lanes, "no device-lane events in the merged trace"
-        assert all(e["name"].startswith("kernel:") for e in lanes)
-        assert all(e["name"].split(":", 1)[1] in KERNELS for e in lanes)
-
-        devs = {e["args"]["drain"]: e for e in events
-                if e["ph"] == "X" and e["name"] == "device_dispatch"}
-        assert devs
-        for lane in lanes:
-            dev = devs[lane["args"]["drain"]]
-            # strict timewise nesting inside the owning drain's span
-            assert lane["ts"] >= dev["ts"] - 0.5, (lane, dev)
-            assert (lane["ts"] + lane["dur"]
-                    <= dev["ts"] + dev["dur"] + 0.5), (lane, dev)
-        for did, dev in devs.items():
-            in_span = [e for e in lanes if e["args"]["drain"] == did]
-            assert sum(e["dur"] for e in in_span) <= dev["dur"] * 1.01 + 0.5
+        with jax.profiler.trace(str(tmp_path)):
+            sched.schedule_pending()
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        pd = jax.profiler.ProfileData.from_file(path)
+        host = next(p for p in pd.planes if p.name == "/host:CPU")
+        events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                  for line in host.lines for e in line.events]
+        dispatches = [e for e in events if e[0].startswith("dispatch:")]
+        devs = [e for e in events if e[0] == "device_dispatch"]
+        assert devs and dispatches
+        assert {e[0].split(":", 1)[1] for e in dispatches} <= set(KERNELS)
+        inside = [e for e in dispatches
+                  if any(d[1] <= e[1] and e[2] <= d[2] for d in devs)]
+        # the drain's kernels dispatch inside device_dispatch; the
+        # cluster probe follows it
+        assert {e[0] for e in inside} - {"dispatch:cluster_probe"}, events
 
 
 # ---------------------------------------------------------------------------
