@@ -582,9 +582,7 @@ class SchedulingQueue:
                                             old_obj=None, new_obj=None,
                                             precheck: Optional[Callable] = None) -> int:
         """scheduling_queue.go:1188. Returns number of pods moved."""
-        if self.in_flight_pods:
-            self.in_flight_events.append(_InFlightEvent(
-                next(self._event_seq), event, old_obj, new_obj))
+        self.log_in_flight_bulk(event, ((old_obj, new_obj),))
         moved = 0
         for uid, qpi in list(self.unschedulable_pods.items()):
             if qpi.gated:
@@ -602,6 +600,18 @@ class SchedulingQueue:
                 self.nominator.add(qpi)
             moved += 1
         return moved
+
+    def log_in_flight_bulk(self, event: ClusterEvent, pairs) -> None:
+        """Append one in-flight event log entry per (old, new) pair, in
+        pair order, while pods are in flight: what
+        move_all_to_active_or_backoff_queue(event, old, new) logs before
+        its sweep. A caller that knows the unschedulable pods hold
+        nothing the sweep could move logs a batch here and skips it."""
+        if self.in_flight_pods and pairs:
+            seq = self._event_seq
+            self.in_flight_events.extend(
+                _InFlightEvent(next(seq), event, old, new)
+                for old, new in pairs)
 
     def gated_pods_could_be_ungated(self) -> list[QueuedPodInfo]:
         return [q for q in self.unschedulable_pods.values() if q.gated]

@@ -285,6 +285,8 @@ LOOP_PHASES = ("schedule_pending", "queue_drain", "batch_build",
                "pod_delete")
 # background threads whose CPU time thread_cpu_seconds counts
 SIDE_THREADS = ("audit", "host_profiler")
+# the handler paths that confirm a bind echo (bind_echo_pods_total)
+BIND_ECHO_PATHS = ("bulk", "per_pod")
 CROSS_SHARD_OUTCOMES = ("conflict", "fenced")
 
 
@@ -465,6 +467,15 @@ class SchedulerMetrics:
             "samples). They compete with the host loop for the "
             "interpreter lock.",
             ("thread",)))
+        self.bind_echo_pods = r.register(Counter(
+            n + "bind_echo_pods_total",
+            "Pods whose bind the watch echoed back, by the handler path "
+            "that confirmed them: bulk (one pass over a bind_all batch of "
+            "pods this scheduler assumed) or per_pod (the informer "
+            "handler: foreign binds, and batches arriving while pods are "
+            "unschedulable, bind errors are pending or pods wait at "
+            "Permit).",
+            ("path",)))
         self.events_total = r.register(Counter(
             n + "events_total",
             "Scheduling events emitted by the event recorder, by type "
@@ -721,6 +732,8 @@ class SchedulerMetrics:
             self.gc_pause_seconds.inc(str(gen), by=0)
         for thread in SIDE_THREADS:
             self.thread_cpu_seconds.inc(thread, by=0)
+        for path in BIND_ECHO_PATHS:
+            self.bind_echo_pods.inc(path, by=0)
         # remaining registered-but-unseeded series: dashboards and
         # bench_metrics.prom must carry every series even when the run
         # never observes them (no permit waits, no divergence, no events)

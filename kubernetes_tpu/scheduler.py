@@ -1036,6 +1036,7 @@ class Scheduler:
                 self.cache.add_pod(new)
                 self.queue.delete(new)
                 self._journey_confirm([new.uid])
+                self.metrics.bind_echo_pods.inc("per_pod")
                 self.queue.move_all_to_active_or_backoff_queue(
                     EVENT_ASSIGNED_POD_ADD, old, new)
         elif self._responsible(new):
@@ -1059,18 +1060,20 @@ class Scheduler:
         shape — our own bulk bind confirming pods we assumed — collapses
         to one pass over the batch instead of the per-pod informer dance
         (workload bookkeeping, a fresh _PodState, four queue dict probes
-        and a move_all sweep per pod). Anything off-shape, or any queue
-        state the per-pod path would consult (unschedulable pods whose
-        queueing hints need the individual pod, in-flight event logging,
-        pending bind errors), falls back to `_on_pod_update` per pod —
-        semantics stay identical by construction."""
+        and a move_all sweep per pod). Anything off-shape, or any state
+        the per-pod path would consult (unschedulable pods whose queueing
+        hints need the individual pod, pending bind errors, pods waiting
+        at Permit), falls back to `_on_pod_update` per pod — semantics
+        stay identical by construction. While other drains are in
+        flight the pass writes the in-flight event log itself, one
+        AssignedPodAdd entry per confirmed pod in pair order, as the
+        per-pod move_all calls would."""
         with self._phase("pod_update", pods=len(pairs)):
             self._update_pods(pairs)
 
     def _update_pods(self, pairs: list) -> None:
         q = self.queue
-        if (q.unschedulable_pods or q.in_flight_pods
-                or self._bind_errors or self._waiting_pods):
+        if q.unschedulable_pods or self._bind_errors or self._waiting_pods:
             for old, new in pairs:
                 self._on_pod_update(old, new)
             return
@@ -1079,22 +1082,30 @@ class Scheduler:
         active = q.active_q
         backoff = q.backoff_q
         confirm: list = []
-        for old, new in pairs:
+        logged = 0      # pairs[:logged] have their in-flight log entries
+        for i, (old, new) in enumerate(pairs):
             uid = new.metadata.uid
             if (not new.spec.node_name or old.spec.node_name
                     or uid not in assumed):
+                # log the confirmed pairs before it first: event seq
+                # numbers follow pair order, as on the per-pod path
+                q.log_in_flight_bulk(EVENT_ASSIGNED_POD_ADD,
+                                     pairs[logged:i])
+                logged = i + 1
                 self._on_pod_update(old, new)
                 continue
             wm_update(old, new)
             confirm.append(new)
             if uid in active or uid in backoff:
                 q.delete(new)
+        # EVENT_ASSIGNED_POD_ADD per confirmed pod: the in-flight log
+        # entry only — with no unschedulable pods (checked above) the
+        # move_all sweep has nothing to move
+        q.log_in_flight_bulk(EVENT_ASSIGNED_POD_ADD, pairs[logged:])
         if confirm:
             self.cache.confirm_bound(confirm)
             self._journey_confirm([p.uid for p in confirm])
-            # EVENT_ASSIGNED_POD_ADD move sweep: with no unschedulable
-            # pods and no in-flight event log (checked above) the per-pod
-            # move_all calls are no-ops — elided wholesale
+            self.metrics.bind_echo_pods.inc("bulk", by=len(confirm))
 
     def _on_pod_delete(self, pod: Pod) -> None:
         # one per pod: the self-time mark alone, no span
