@@ -279,7 +279,8 @@ SHARD_STEAL_REASONS = ("split", "merge", "steal", "rebalance")
 # schedule_pending, the drain phases in loop order, the informer handlers
 LOOP_PHASES = ("schedule_pending", "queue_drain", "batch_build",
                "host_build", "host_snapshot", "host_tensorize",
-               "host_group_seed", "host_cache", "audit", "device_dispatch",
+               "host_group_seed", "ipa_seed", "host_cache", "audit",
+               "device_dispatch",
                "cluster_probe", "readback", "commit", "bookkeeping",
                "release", "dispatcher_flush", "pod_add", "pod_update",
                "pod_delete")
@@ -287,6 +288,9 @@ LOOP_PHASES = ("schedule_pending", "queue_drain", "batch_build",
 SIDE_THREADS = ("audit", "host_profiler")
 # the handler paths that confirm a bind echo (bind_echo_pods_total)
 BIND_ECHO_PATHS = ("bulk", "per_pod")
+# the placement tiers that resolve a drain's pods (placement_pods_total)
+PLACEMENT_TIERS = ("uniform", "wave_merge", "wave_serial", "wavescan", "scan",
+                   "gang", "host_greedy", "host")
 CROSS_SHARD_OUTCOMES = ("conflict", "fenced")
 
 
@@ -476,6 +480,16 @@ class SchedulerMetrics:
             "unschedulable, bind errors are pending or pods wait at "
             "Permit).",
             ("path",)))
+        self.placement_pods = r.register(Counter(
+            n + "placement_pods_total",
+            "Pods resolved by each placement tier, counted once per span "
+            "when its result is read: uniform (run_uniform's closed form), "
+            "wave_merge and wave_serial (run_wave's merge waves and the "
+            "serial scan that finishes their remainder), wavescan "
+            "(run_plan), scan (run_batch), gang (run_gang), host_greedy "
+            "(the host's vectorized greedy) and host (the per-pod host "
+            "oracle).",
+            ("tier",)))
         self.events_total = r.register(Counter(
             n + "events_total",
             "Scheduling events emitted by the event recorder, by type "
@@ -734,6 +748,8 @@ class SchedulerMetrics:
             self.thread_cpu_seconds.inc(thread, by=0)
         for path in BIND_ECHO_PATHS:
             self.bind_echo_pods.inc(path, by=0)
+        for tier in PLACEMENT_TIERS:
+            self.placement_pods.inc(tier, by=0)
         # remaining registered-but-unseeded series: dashboards and
         # bench_metrics.prom must carry every series even when the run
         # never observes them (no permit waits, no divergence, no events)

@@ -33,6 +33,7 @@ oracle individually — never the whole batch.
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import NamedTuple, Optional
 
@@ -621,6 +622,9 @@ class GroupManager:
         # and seed_counts (ingest/groupcols.py): the per-call O(N) tv /
         # dom / presence walks now run once per node-state change
         self.cols = NodeLabelColumns(state)
+        # entered around each row's inter-pod-affinity half of
+        # seed_counts: the owner's timing phase, a no-op by default
+        self.ipa_phase = nullcontext
 
     # -- storage --------------------------------------------------------------
 
@@ -995,58 +999,59 @@ class GroupManager:
                 out["spr_s_cnt"][r, j] = gather_ids(
                     cols.tv(c.topology_key),
                     cols.value_ids(c.topology_key, by_tv), np.int32)
-            # inter-pod affinity maps via the plugin's PreFilter. Empty
-            # count maps (the common fresh-workload case) skip their
-            # gathers outright — the arrays are zeros.
-            cs = CycleState()
-            self.ipa.pre_filter(cs, pod, node_list)
-            s = cs.read_or_none(ipa_mod._PRE_FILTER_KEY)
-            if s is not None:
-                out["ipa_a_total"][r] = sum(s.affinity_counts.values())
-                if s.existing_anti_affinity_counts:
-                    # counts keyed (label key, value): a node contributes
-                    # each (k, v) it carries — per distinct k, one gather
-                    by_key: dict = {}
-                    for (lk, lv), c0 in \
-                            s.existing_anti_affinity_counts.items():
-                        by_key.setdefault(lk, {})[lv] = c0
-                    veto = out["ipa_veto"][r]
-                    for lk, vals in by_key.items():
-                        veto += gather_ids(cols.tv(lk),
-                                           cols.value_ids(lk, vals),
-                                           np.int32)
-                if s.affinity_counts:
-                    by_key = {}
-                    for (tk, tv), c0 in s.affinity_counts.items():
-                        by_key.setdefault(tk, {})[tv] = c0
-                    for t, term in enumerate(info.req_a):
-                        vals = by_key.get(term.topology_key)
-                        if vals:
-                            out["ipa_a_cnt"][r, t] = gather_ids(
-                                cols.tv(term.topology_key),
-                                cols.value_ids(term.topology_key, vals),
-                                np.int32)
-                if s.anti_affinity_counts:
-                    by_key = {}
-                    for (tk, tv), c0 in s.anti_affinity_counts.items():
-                        by_key.setdefault(tk, {})[tv] = c0
-                    for t, term in enumerate(info.req_aa):
-                        vals = by_key.get(term.topology_key)
-                        if vals:
-                            out["ipa_aa_cnt"][r, t] = gather_ids(
-                                cols.tv(term.topology_key),
-                                cols.value_ids(term.topology_key, vals),
-                                np.int32)
-            # symmetric score surface via the plugin's PreScore
-            cs = CycleState()
-            self.ipa.pre_score(cs, pod, node_list, all_nodes=node_list)
-            ps = cs.read_or_none(ipa_mod._PRE_SCORE_KEY)
-            if ps is not None and ps.topology_score:
-                score = out["ipa_score"][r]
-                for tk, tv_scores in ps.topology_score.items():
-                    score += gather_ids(cols.tv(tk),
-                                        cols.value_ids(tk, tv_scores),
-                                        np.int64)
+            with self.ipa_phase():
+                # inter-pod affinity maps via the plugin's PreFilter. Empty
+                # count maps (the common fresh-workload case) skip their
+                # gathers outright — the arrays are zeros.
+                cs = CycleState()
+                self.ipa.pre_filter(cs, pod, node_list)
+                s = cs.read_or_none(ipa_mod._PRE_FILTER_KEY)
+                if s is not None:
+                    out["ipa_a_total"][r] = sum(s.affinity_counts.values())
+                    if s.existing_anti_affinity_counts:
+                        # counts keyed (label key, value): a node contributes
+                        # each (k, v) it carries — per distinct k, one gather
+                        by_key: dict = {}
+                        for (lk, lv), c0 in \
+                                s.existing_anti_affinity_counts.items():
+                            by_key.setdefault(lk, {})[lv] = c0
+                        veto = out["ipa_veto"][r]
+                        for lk, vals in by_key.items():
+                            veto += gather_ids(cols.tv(lk),
+                                               cols.value_ids(lk, vals),
+                                               np.int32)
+                    if s.affinity_counts:
+                        by_key = {}
+                        for (tk, tv), c0 in s.affinity_counts.items():
+                            by_key.setdefault(tk, {})[tv] = c0
+                        for t, term in enumerate(info.req_a):
+                            vals = by_key.get(term.topology_key)
+                            if vals:
+                                out["ipa_a_cnt"][r, t] = gather_ids(
+                                    cols.tv(term.topology_key),
+                                    cols.value_ids(term.topology_key, vals),
+                                    np.int32)
+                    if s.anti_affinity_counts:
+                        by_key = {}
+                        for (tk, tv), c0 in s.anti_affinity_counts.items():
+                            by_key.setdefault(tk, {})[tv] = c0
+                        for t, term in enumerate(info.req_aa):
+                            vals = by_key.get(term.topology_key)
+                            if vals:
+                                out["ipa_aa_cnt"][r, t] = gather_ids(
+                                    cols.tv(term.topology_key),
+                                    cols.value_ids(term.topology_key, vals),
+                                    np.int32)
+                # symmetric score surface via the plugin's PreScore
+                cs = CycleState()
+                self.ipa.pre_score(cs, pod, node_list, all_nodes=node_list)
+                ps = cs.read_or_none(ipa_mod._PRE_SCORE_KEY)
+                if ps is not None and ps.topology_score:
+                    score = out["ipa_score"][r]
+                    for tk, tv_scores in ps.topology_score.items():
+                        score += gather_ids(cols.tv(tk),
+                                            cols.value_ids(tk, tv_scores),
+                                            np.int64)
         return out
 
     # -- assembly -------------------------------------------------------------
@@ -1285,16 +1290,20 @@ def wave_fold(gd: GroupsDev, gc: GroupCarry, wt, cnt_sn,
         # the consumer's terms (group_update's tvb_a != 0 gate)
         k_un = jnp.sum(gd.ipa_ra_active[:, :, None]
                        & (gd.ipa_ra_tv != 0), axis=1)     # [U, N]
-        a_add = jnp.einsum(
-            "un,un->u", w_un.astype(jnp.int64), k_un.astype(jnp.int64))
+        # a TPU has no 64-bit integer dot: int64 contractions in this
+        # fold multiply and sum instead of einsum
+        a_add = jnp.sum(w_un.astype(jnp.int64) * k_un.astype(jnp.int64),
+                        axis=1)
         if axis is not None:
             a_add = jax.lax.psum(a_add, axis)
         ipa_a_total = gc.ipa_a_total + a_add
 
     if fam.ipa_score:
+        # the score weights are int64 (no dot on a TPU: multiply and sum)
+        cnt64 = cnt_sn.astype(jnp.int64)
         # consumer-side preferred terms matching the placed pod
-        wc_utn = jnp.einsum("sut,sn->utn", gd.w_stc[wt],
-                            cnt_sn.astype(jnp.int64))
+        wc_utn = jnp.sum(gd.w_stc[wt][:, :, :, None]
+                         * cnt64[:, None, None, :], axis=0)     # [U, CT, N]
         cons_add = jnp.sum(_dom_share(gd.ipa_stc_tv, gd.ipa_stc_dom,
                                       wc_utn, axis, n_seg), axis=1)    # [U, N]
         # placed-side terms: share counts along the placed row's term
@@ -1303,10 +1312,10 @@ def wave_fold(gd: GroupsDev, gc: GroupCarry, wt, cnt_sn,
         stp_dom_w = gd.ipa_stp_dom[wt]
         shared_p = _dom_share(
             stp_tv_w, stp_dom_w,
-            jnp.broadcast_to(cnt_sn.astype(jnp.int64)[:, None, :],
-                             stp_tv_w.shape),
+            jnp.broadcast_to(cnt64[:, None, :], stp_tv_w.shape),
             axis, n_seg)
-        plcd_add = jnp.einsum("sut,stn->un", gd.w_stp[wt], shared_p)
+        plcd_add = jnp.sum(gd.w_stp[wt][:, :, :, None]
+                           * shared_p[:, None, :, :], axis=(0, 2))  # [U, N]
         ipa_score = gc.ipa_score + cons_add + plcd_add
 
     return GroupCarry(spr_f_cnt=spr_f_cnt, spr_f_min_zero=gc.spr_f_min_zero,

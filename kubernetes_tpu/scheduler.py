@@ -26,6 +26,7 @@ import time as _time
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Callable, Optional
 
 import jax.numpy as jnp
@@ -395,6 +396,9 @@ class Scheduler:
                       if p.name() == "InterPodAffinity"), None)
         self.builder = BatchBuilder(self.state, batch_dims,
                                     spread_plugin=spread_p, ipa_plugin=ipa_p)
+        # the inter-pod-affinity half of count seeding, nested in
+        # host_group_seed
+        self.builder.groups.ipa_phase = partial(self._phase, "ipa_seed")
         self.dispatcher = APIDispatcher(
             client=client, on_bind_error=self._on_bind_error)
         self.config = config    # retained: ShardManager reads incident_dir
@@ -2080,6 +2084,7 @@ class Scheduler:
             return None   # normalization preconditions failed: scan path
         with self.tracer.span("host_greedy", pods=n):
             out = hg.run(n)
+        self.metrics.placement_pods.inc("host_greedy", by=n)
         # placements live only in the upcoming commits; the resident device
         # carry (if any) knows nothing of them
         self._invalidate_device_state()
@@ -2171,6 +2176,9 @@ class Scheduler:
             J = 1
         else:
             _L, _K, J = self._uniform_shape(na)
+        # a wave speculates at most one entry per matrix cell (a cluster
+        # smaller than the wave, under jcap=1, has fewer cells than Lw)
+        Lw = min(Lw, K * J)
         norm_live = not self._wave_norm_static((u,))
         carry2, packed = run_wave(
             cfg, na, carry, jnp.asarray(valid), table, jnp.int32(u),
@@ -2766,8 +2774,10 @@ class Scheduler:
 
     def _resolve_records(self, pd: "_PendingDrain", out) -> None:
         """Resolve a drain's device results into `out`, replaying inexact
-        uniform runs (and everything chained downstream) as needed."""
+        uniform runs (and everything chained downstream) as needed. Each
+        span's pods count once under the tier that resolved them."""
         from .perf.ledger import GLOBAL as _ledger
+        placed = self.metrics.placement_pods
         idx = 0
         while idx < len(pd.records):
             rec = pd.records[idx]
@@ -2776,6 +2786,7 @@ class Scheduler:
             m = rec.j - rec.i
             if rec.kind == "scan":
                 out[rec.i:rec.j] = r[:m]
+                placed.inc("scan", by=m)
                 idx += 1
                 continue
             if rec.kind in ("wave", "wavescan"):
@@ -2804,11 +2815,13 @@ class Scheduler:
                 # the all-or-nothing verdict: a rejected gang was already
                 # unwound ON DEVICE — the host only masks the assignments
                 out[rec.i:rec.j] = raw if accepted else np.int32(-1)
+                placed.inc("gang", by=m)
                 idx += 1
                 continue
             exact, depth = bool(r[rec.L]), bool(r[rec.L + 1])
             if exact and depth:
                 out[rec.i:rec.j] = r[:m]
+                placed.inc("uniform", by=m)
                 idx += 1
                 continue
             # rollback: resolve THIS run synchronously from its input carry
@@ -2818,11 +2831,13 @@ class Scheduler:
                 carry = self._uniform_escalate(cfg, pd.na, carry, pd.batch,
                                                rec.i, rec.j, pd.table, out,
                                                rec.J, ovl=pd.ovl)
+                placed.inc("uniform", by=m)
             else:
                 carry, a = self._scan_dispatch(cfg, pd.na, carry, pd.batch,
                                                rec.i, rec.j, pd.table,
                                                ovl=pd.ovl, nom=pd.nom)
                 out[rec.i:rec.j] = np.asarray(a)[:m]
+                placed.inc("scan", by=m)
             self._replay_downstream(pd, idx, carry)
             idx += 1
 
@@ -2856,13 +2871,17 @@ class Scheduler:
     def _observe_wave(self, rec: _RunRec, r, m: int,
                       pd: Optional["_PendingDrain"] = None) -> None:
         """Record a resolved wave's stats: waves executed, conflict ratio
-        (conflict-cut events + serially repaired pods over the span), and
-        the first wave's accepted conflict-free prefix length. Also folds
-        the raw numbers into the drain's flight-recorder entry."""
+        (conflict-cut events + serially repaired pods over the span), the
+        first wave's accepted conflict-free prefix length, and the span's
+        pods by placement tier. Also folds the raw numbers into the
+        drain's flight-recorder entry."""
         B = rec.L
+        placed = self.metrics.placement_pods
         if rec.kind == "wave":
             waves, confs = int(r[B]), int(r[B + 1])
             prefix, serial = int(r[B + 2]), int(r[B + 3])
+            placed.inc("wave_merge", by=m - serial)
+            placed.inc("wave_serial", by=serial)
             self.metrics.wave_placement_waves.inc(by=max(waves, 1))
             self.metrics.wave_conflict_ratio.observe(
                 min((confs + serial) / max(m, 1), 1.0))
@@ -2870,6 +2889,7 @@ class Scheduler:
         else:
             waves, serial = 1, 0
             confs, prefix = int(r[B]), int(r[B + 1])
+            placed.inc("wavescan", by=m)
             self.metrics.wave_placement_waves.inc()
             self.metrics.wave_conflict_ratio.observe(
                 min(confs / max(m, 1), 1.0))
@@ -3582,6 +3602,7 @@ class Scheduler:
         if self._skip_pod_schedule(pod):
             self.queue.done(pod.uid)
             return False
+        self.metrics.placement_pods.inc("host")
         self.cache.update_snapshot(self.snapshot)
         state = CycleState()
         # plugin_execution_duration sampling: ~10% of host cycles

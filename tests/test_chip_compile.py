@@ -2,9 +2,11 @@
 run here.
 
 - AOT compiles of `run_batch`, `run_uniform`, `scatter_rows` and `run_gang`
-  at the 5000-node bucket (8192 node rows, 8192-pod batch, int64) for one
-  described v5e device: what the chip's compiler refuses fails here, at no
-  chip time. Nothing runs, so these say nothing about results or speed.
+  at the 5000-node bucket (8192 node rows, 8192-pod batch, int64), and of
+  `run_wave` for a 512-pod hostname anti-affinity span (the inter-pod
+  families of its wave fold), for one described v5e device: what the
+  chip's compiler refuses fails here, at no chip time. Nothing runs, so
+  these say nothing about results or speed.
 - chip_smoke.py's cases at their 500-node shapes with the accelerator
   branch of the device path forced (buffer donation), through the
   script's own case runner: every pod bound, nothing on the host, zero
@@ -27,6 +29,7 @@ from kubernetes_tpu.perf.harness import BATCH_SIZE as BATCH
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NODES = 5000          # the upstream scheduler_perf 5k-node shapes
 GANG = 256            # GangTraining/5000Nodes gang size
+BATCH_WAVE = 512      # the default batchSize: one wave span per drain
 HBM_BYTES = 16 * 2**30
 
 
@@ -100,6 +103,54 @@ def cluster():
             "carry0": initial_carry(na)}
 
 
+@pytest.fixture(scope="module")
+def anti_cluster():
+    """Host-side inputs at the 5000-node bucket for a same-signature
+    required hostname anti-affinity row (the SchedulingPodAntiAffinity
+    template) with resident anti pods: run_wave's domain-veto merge tier,
+    the ipa_anti and ipa_score families of its wave fold."""
+    from kubernetes_tpu.backend.cache import Cache, Snapshot
+    from kubernetes_tpu.compiler import DrainCompiler
+    from kubernetes_tpu.ops.groups import to_device
+    from kubernetes_tpu.ops.program import initial_carry, pod_rows_from_batch
+    from kubernetes_tpu.state.batch import BatchBuilder
+    from kubernetes_tpu.state.tensorize import ClusterState
+    from kubernetes_tpu.testing.wrappers import make_node, make_pod
+
+    host = "kubernetes.io/hostname"
+
+    def anti(name, ns):
+        return (make_pod(name, ns).req({"cpu": "100m", "memory": "500Mi"})
+                .label("color", "green")
+                .pod_affinity(host, {"color": "green"}, anti=True,
+                              namespaces=("sched-1", "sched-0")))
+
+    cache = Cache()
+    for i in range(NODES):
+        cache.add_node(make_node(f"n{i}").capacity(
+            {"cpu": 4, "memory": "32Gi", "pods": 110})
+            .label(host, f"n{i}").obj())
+    for i in range(8):
+        cache.add_pod(anti(f"init-{i}", "sched-0").node(f"n{i}").obj())
+    snap = Snapshot()
+    cache.update_snapshot(snap)
+    state = ClusterState()
+    state.apply_snapshot(snap, full=True)
+    builder = BatchBuilder(state)
+    batch = builder.build([anti(f"p{i}", "sched-1").obj()
+                           for i in range(512)], pad_to=512)
+    na = state.device_arrays()
+    gd_np, gc_np = builder.groups.build_dev(snap)
+    _xs, table = pod_rows_from_batch(batch)
+    row = int(batch.tidx[0])
+    compiler = DrainCompiler(state=state, builder=builder, gates=None)
+    return {"na": na, "table": table, "row": row, "gd": to_device(gd_np),
+            "fam": builder.groups.families(snap),
+            "carry": initial_carry(na, to_device(gc_np)),
+            "statics": compiler.surfaces.get(na, table, (row,))[0],
+            "mode": compiler._wave_same_mode(row)}
+
+
 def _uniform_lkj(L: int, n_rows: int):
     """Scheduler._uniform_shape / _gang_dispatch's (L, K, J) choice."""
     from kubernetes_tpu.state.tensorize import pow2_at_least
@@ -133,6 +184,15 @@ def _lower(kernel: str, c: dict, spec):
         rows = jax.tree_util.tree_map(lambda a: a[:64], na)
         return P._scatter_rows_jit.lower(
             spec(na), spec(np.zeros(64, np.int32)), spec(rows))
+    if kernel == "run_wave":
+        # Scheduler._wave_dispatch's statics for a 512-pod anti span
+        a = c["anti"]
+        _mode, anti_term = a["mode"]
+        return P._run_wave_same_fn(True).lower(
+            cfg, spec(a["na"]), spec(a["carry"]),
+            spec(np.ones(BATCH_WAVE, bool)), spec(a["table"]),
+            spec(np.int32(a["row"])), spec(a["gd"]), spec(a["statics"]),
+            BATCH_WAVE, 1, BATCH_WAVE, a["fam"], False, anti_term, True)
     assert kernel == "run_gang"
     # the scan tier: the uniform tier shares run_uniform's _uniform_core
     row = int(batch.tidx[0])
@@ -148,10 +208,13 @@ def _lower(kernel: str, c: dict, spec):
 
 
 @pytest.mark.parametrize("kernel", ["run_batch", "run_uniform",
-                                    "scatter_rows", "run_gang"])
+                                    "scatter_rows", "run_gang", "run_wave"])
 def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache,
-                                 cluster):
+                                 cluster, request):
     import jax
+
+    if kernel == "run_wave":
+        cluster = dict(cluster, anti=request.getfixturevalue("anti_cluster"))
 
     def spec(tree):
         return jax.tree_util.tree_map(
