@@ -286,6 +286,9 @@ LOOP_PHASES = ("schedule_pending", "queue_drain", "batch_build",
                "pod_delete")
 # background threads whose CPU time thread_cpu_seconds counts
 SIDE_THREADS = ("audit", "host_profiler")
+# where a shadow-audit record's host-oracle replay runs
+# (shadow_audit_replays_total)
+AUDIT_REPLAY_ROUTES = ("process", "thread")
 # the handler paths that confirm a bind echo (bind_echo_pods_total)
 BIND_ECHO_PATHS = ("bulk", "per_pod")
 # the placement tiers that resolve a drain's pods (placement_pods_total)
@@ -467,7 +470,9 @@ class SchedulerMetrics:
         self.thread_cpu_seconds = r.register(Counter(
             n + "thread_cpu_seconds_total",
             "CPU seconds of the scheduler's own background work, by "
-            "thread: audit (shadow-oracle replays), host_profiler (stack "
+            "thread: audit (the shadow audit's thread: replays routed to "
+            "it, and for those routed to the worker process their "
+            "pickling, the wait and publication), host_profiler (stack "
             "samples). They compete with the host loop for the "
             "interpreter lock.",
             ("thread",)))
@@ -562,6 +567,20 @@ class SchedulerMetrics:
             n + "audit_replay_seconds",
             "Wall time of one shadow-audit host-oracle replay "
             "(background worker, off the hot path)."))
+        self.shadow_audit_replays = r.register(Counter(
+            n + "shadow_audit_replays_total",
+            "Shadow-audit records replayed, by where the host-oracle "
+            "replay ran: process (the audit's worker process, off the "
+            "scheduler's interpreter lock) or thread (the audit thread: "
+            "synchronous audits, plugin sets other than the default, "
+            "drains with volumes or resource claims).",
+            ("where",)))
+        self.audit_worker_cpu_seconds = r.register(Counter(
+            n + "audit_worker_cpu_seconds_total",
+            "CPU seconds the shadow audit's worker process spent on the "
+            "records it replayed. They do not compete for the "
+            "scheduler's interpreter lock, so thread_cpu_seconds_total "
+            "leaves them out."))
         self.explain_duration = r.register(Histogram(
             n + "explain_seconds",
             "Wall time of one /debug/explain decision decomposition "
@@ -814,6 +833,9 @@ class SchedulerMetrics:
         for outcome in ("clean", "divergent", "skipped", "error"):
             self.shadow_audit_drains.inc(outcome, by=0)
         self.audit_replay_duration.seed()
+        for where in AUDIT_REPLAY_ROUTES:
+            self.shadow_audit_replays.inc(where, by=0)
+        self.audit_worker_cpu_seconds.inc(by=0)
         self.explain_duration.seed()
         from ..obs.slo import DEFAULT_OBJECTIVES, WINDOWS
         for sli in DEFAULT_OBJECTIVES:

@@ -5,9 +5,10 @@ default host plugins — is verified offline by the parity fuzz suites;
 nothing watched it in live operation. This module is the always-on half
 (`ShadowOracleAudit` gate): a sampler captures a deterministic replay
 record per sampled drain, appends it to a hash-chained drain ledger, and
-a background worker re-executes the record through the HOST ORACLE
-(framework.runtime.schedule_pod — the real plugin implementations, not
-the kernels) and diffs:
+a background thread hands the record to the replay (`obs/replay.py`),
+which re-executes it through the HOST ORACLE (framework.runtime
+.schedule_pod — the real plugin implementations, not the kernels) and
+diffs:
 
   - per-pod assignments           → oracle_divergence_total{kind=assignment}
   - scheduled/unschedulable       → oracle_divergence_total{kind=verdict}
@@ -19,10 +20,13 @@ commits and refreshes the snapshot before cloning), so the cloned
 NodeInfos are exactly the state the device carry encodes — a divergence
 is a real decision difference, never capture skew. The replay itself is
 bounded (`shadow_audit_max_replay_pods` prefix — the serial greedy's
-first K decisions depend only on prior state) and runs off the hot path
-on a daemon worker; reason diffs only run on fully-replayed drains and
-only when no external cluster event landed between dispatch and commit
-(the device diagnoses against the commit-time snapshot).
+first K decisions depend only on prior state) and runs off the hot path:
+in a worker process (`obs/replay.py`), off the scheduler's interpreter
+lock, or on the audit thread when the record needs the live framework
+(`replay_plugins`) or the audit is synchronous. Reason diffs only run on
+fully-replayed drains and only when no external cluster event landed
+between dispatch and commit (the device diagnoses against the
+commit-time snapshot).
 
 The ledger is a hash chain: each record's sha256 covers the previous
 hash plus the input fingerprints (pod-table rows, node statics gen, plan
@@ -44,13 +48,14 @@ import pickle
 import queue as _queue
 import threading
 import time as _time
+import weakref
 from dataclasses import dataclass, field
 from typing import Optional
 
 from jax.profiler import TraceAnnotation
 
-from ..framework.interface import CycleState
-from ..framework.types import Diagnosis, FitError, PodInfo
+from .replay import (ReplayWorker, WorkerDied, namespace_map,
+                     replay_plugins, verdict)
 
 GENESIS = "0" * 64
 
@@ -58,7 +63,18 @@ GENESIS = "0" * 64
 # "skipped") instead of growing without bound — the audit must never
 # become a memory leak when the worker falls behind a 100%-sampled soak
 MAX_QUEUE = 64
-
+# audit threads of the process route, each with its own worker process,
+# that the audits of one interpreter run at once: at most 4, and one core
+# fewer than the host has, which the scheduling loop keeps. An audit adds
+# one while more of its records are outstanding than it has threads (a
+# 64-pod replay at 5000 nodes takes 7-9 s of a worker; 1/64 of 16
+# drains/s samples one every 4 s). Every audit may always run one, so the
+# schedulers of one process never queue behind each other: N audits run
+# at most max(N, MAX_REPLAY_WORKERS) workers.
+MAX_REPLAY_WORKERS = max(1, min(4, (os.cpu_count() or 1) - 1))
+# the live process-route threads of every audit (the cap above)
+_couriers: "weakref.WeakSet" = weakref.WeakSet()
+_couriers_lock = threading.Lock()
 
 def _sha(*parts) -> str:
     h = hashlib.sha256()
@@ -83,6 +99,12 @@ class AuditRecord:
     weights: dict                  # plugin weights (CLI framework rebuild)
     pods: list                     # [(uid, Pod, PodInfo)] in queue order
     nodes: list                    # PRIVATE NodeInfo clones at capture
+    # the replay plugins by name (obs/replay.replay_plugins), when a
+    # framework rebuilt from them decides as the live one; None: the
+    # replay needs the live framework and runs on the audit thread
+    plugins: Optional[tuple] = None
+    # InterPodAffinity's namespace → labels map, for that rebuild
+    namespaces: dict = field(default_factory=dict)
     # monotonic ledger sequence number, assigned at append: the tail
     # cursor for streaming subscribers (ha/standby.py). 0 = unappended.
     seq: int = 0
@@ -96,7 +118,7 @@ class AuditRecord:
     device: dict = field(default_factory=dict)      # uid → node | None
     reasons_dev: dict = field(default_factory=dict)  # uid → message
     reasons_ok: bool = True        # False: skip the reason diff
-    # filled by the worker
+    # filled by the audit thread (the verdict)
     outcome: str = "pending"       # clean|divergent|skipped|error|pending
     skip_reason: str = ""
     oracle: dict = field(default_factory=dict)
@@ -134,11 +156,14 @@ class AuditRecord:
         return d
 
     def to_payload(self) -> dict:
-        """Standalone-replayable pickle payload (tools/audit_replay.py):
-        everything but the live framework and device arrays."""
+        """Standalone-replayable payload (obs/replay.replay; the worker
+        process and tools/audit_replay.py): everything but the live
+        framework and device arrays."""
         return {
             "drainId": self.drain_id, "profile": self.profile_name,
             "strategy": self.strategy, "weights": dict(self.weights),
+            "plugins": self.plugins,
+            "namespaces": dict(self.namespaces),
             "pods": [(uid, pod, pi) for uid, pod, pi in self.pods],
             "nodes": self.nodes,
             "fingerprints": dict(self.fingerprints),
@@ -171,103 +196,6 @@ class ExplainCtx:
 
 
 # ---------------------------------------------------------------------------
-# host-oracle replay (shared by the worker and tools/audit_replay.py)
-
-
-def replay_decisions(framework, nodes: list, pods: list,
-                     device: Optional[dict] = None, cap: int = 0):
-    """Serial host-oracle replay over PRIVATE NodeInfo clones (mutated in
-    place). Returns (oracle {uid: verdict dict | None}, reasons
-    {uid: message}, truncated).
-
-    The verdict dict carries `host` (the oracle's own tie-break pick),
-    `argmax` (EVERY node tied at max score — the reference breaks ties
-    with a seeded RNG, so any member is a correct decision:
-    runtime.ScheduleResult.argmax_set is the system's documented parity
-    contract) and `scores`. When `device` decisions are given, the
-    replay FOLLOWS the device's placements for pods the device bound, so
-    each step is judged against the actual committed state and one wrong
-    decision counts once instead of cascading.
-
-    Reasons are computed against the POST-REPLAY state — mirroring the
-    device path, whose mask diagnosis runs against the post-commit
-    snapshot (scheduler._device_fit_error)."""
-    from ..framework.runtime import schedule_pod
-    limit = len(pods) if cap <= 0 else min(cap, len(pods))
-    truncated = limit < len(pods)
-    by_name = {ni.name: ni for ni in nodes}
-    oracle: dict = {}
-    failed: list = []
-    for uid, pod, pi in pods[:limit]:
-        state = CycleState()
-        try:
-            result = schedule_pod(framework, state, pod, nodes)
-            oracle[uid] = {"host": result.suggested_host,
-                           "argmax": set(result.argmax_set),
-                           "scores": dict(result.scores)}
-        except FitError:
-            oracle[uid] = None
-            failed.append((uid, pod))
-        # apply the COMMITTED placement (fall back to the oracle's own
-        # pick when no device decision is recorded for this pod)
-        placed = None
-        if device is not None:
-            placed = device.get(uid)
-        elif oracle[uid] is not None:
-            placed = oracle[uid]["host"]
-        if placed is not None and placed in by_name:
-            assumed = pod.with_node_name(placed)
-            by_name[placed].add_pod(
-                PodInfo(pod=assumed, requests=pi.requests,
-                        cpu_nonzero=pi.cpu_nonzero,
-                        mem_nonzero=pi.mem_nonzero))
-    reasons: dict = {}
-    for uid, pod in failed:
-        state = CycleState()
-        diagnosis = Diagnosis()
-        pre_result, status = framework.run_pre_filter_plugins(state, pod,
-                                                              nodes)
-        if not status.is_success():
-            diagnosis.pre_filter_msg = "; ".join(status.reasons)
-            if status.plugin:
-                diagnosis.unschedulable_plugins.add(status.plugin)
-        else:
-            framework.find_nodes_that_pass_filters(state, pod, nodes,
-                                                   pre_result, diagnosis)
-        reasons[uid] = str(FitError(pod, len(nodes), diagnosis))
-    return oracle, reasons, truncated
-
-
-def diff_decisions(rec_device: dict, rec_reasons: dict, oracle: dict,
-                   oracle_reasons: dict, reasons_ok: bool = True) -> dict:
-    """Assignment/verdict/reason diffs over the replayed pod set. An
-    assignment diverges when the device's choice lands OUTSIDE the
-    oracle's argmax set — any tied node is a correct decision (the
-    reference's randomized tie-break), so tie-order differences (e.g.
-    node churn reordering the zone round-robin list against the device
-    row order) are not divergences."""
-    diffs: dict = {"assignment": [], "verdict": [], "reason": []}
-    for uid, verdict in oracle.items():
-        d_node = rec_device.get(uid)
-        if (d_node is None) != (verdict is None):
-            diffs["verdict"].append(
-                {"pod": uid, "device": d_node,
-                 "oracle": verdict["host"] if verdict else None})
-        elif verdict is not None and d_node not in verdict["argmax"]:
-            diffs["assignment"].append(
-                {"pod": uid, "device": d_node, "oracle": verdict["host"],
-                 "deviceScore": verdict["scores"].get(d_node),
-                 "oracleScore": verdict["scores"].get(verdict["host"])})
-        elif d_node is None and reasons_ok:
-            d_msg = rec_reasons.get(uid, "")
-            o_msg = oracle_reasons.get(uid, "")
-            if d_msg != o_msg:
-                diffs["reason"].append(
-                    {"pod": uid, "device": d_msg, "oracle": o_msg})
-    return {k: v for k, v in diffs.items() if v}
-
-
-# ---------------------------------------------------------------------------
 # hash-chained drain ledger
 
 
@@ -275,7 +203,7 @@ class DrainLedger:
     """Fixed-capacity ring of AuditRecords forming a hash chain.
 
     Appended by the scheduling thread at capture time (chain order ==
-    dispatch order), outcome fields updated in place by the audit worker,
+    dispatch order), outcome fields updated in place by the audit thread,
     read by the debug HTTP thread AND tailed by a standby scheduler
     (ha/standby.py). Three threads touch live records concurrently, so
     the discipline is explicit:
@@ -288,7 +216,7 @@ class DrainLedger:
       snapshot without holding the lock;
     - every OTHER record field (device decisions, outcome, diffs, the
       replay-payload clears) mutates only under `lock` — the audit
-      worker takes it via the `lock` property, so a tail subscriber
+      thread takes it via the `lock` property, so a tail subscriber
       never observes a half-written outcome or a nodes list being
       cleared mid-iteration.
     """
@@ -310,7 +238,7 @@ class DrainLedger:
     @property
     def lock(self):
         """The ledger lock, shared with record mutators (the audit
-        worker) and tail subscribers so record field updates are atomic
+        thread) and tail subscribers so record field updates are atomic
         with respect to reads — see the class docstring discipline."""
         return self._lock
 
@@ -447,7 +375,7 @@ class DrainLedger:
     def dump(self, limit: int = 0, details: bool = False) -> dict:
         valid = self.verify()
         with self._lock:
-            # to_dict reads worker-mutated fields (outcome, diffs):
+            # to_dict reads thread-mutated fields (outcome, diffs):
             # serialize under the lock so a concurrent _process can't
             # hand the HTTP thread a half-written record
             recs = list(self.ring)
@@ -459,12 +387,17 @@ class DrainLedger:
 
 
 # ---------------------------------------------------------------------------
-# the audit sampler + background worker
+# the audit sampler + audit thread
 
 
 class ShadowOracleAudit:
-    """See module docstring. Owned by one Scheduler; the worker thread is
-    lazy (first sampled drain) and a daemon."""
+    """See module docstring. Owned by one Scheduler; its threads are its
+    own, lazy (the first record of their route) and daemons. Records that
+    replay through the live framework go to one serial thread, as their
+    replay holds the interpreter lock. Records routed to a worker process
+    go to couriers (`_AuditThread`), each with its own worker, added while
+    more such records are outstanding than couriers run, up to
+    MAX_REPLAY_WORKERS, so the replays keep pace with the sampler."""
 
     def __init__(self, sample_rate: float = 1.0 / 64.0,
                  max_replay_pods: int = 64, dirpath: str = "",
@@ -479,9 +412,15 @@ class ShadowOracleAudit:
         self.synchronous = synchronous
         self.gates_fp = gates_fingerprint(gates) if gates is not None else ""
         self._accum = 0.0
-        self._queue: _queue.Queue = _queue.Queue()
-        self._worker: Optional[threading.Thread] = None
-        self._worker_lock = threading.Lock()
+        # submitted records by route (metrics.AUDIT_REPLAY_ROUTES): to a
+        # courier's worker process, or to the serial thread
+        self._queues = {"process": _queue.Queue(), "thread": _queue.Queue()}
+        # guards the thread lists and the metrics the threads publish to
+        self._lock = threading.Lock()
+        self._threads = {"process": [], "thread": []}  # guarded_by: _lock
+        # the audit threads hold their audit weakly: a dropped Scheduler
+        # takes its audit, threads and worker processes with it
+        weakref.finalize(self, _end_threads, self._queues)
 
     # -- sampling -------------------------------------------------------------
 
@@ -494,7 +433,7 @@ class ShadowOracleAudit:
         if self._accum < 1.0:
             return False
         self._accum -= 1.0
-        if self._queue.qsize() >= MAX_QUEUE:
+        if self._backlog() >= MAX_QUEUE:
             self._count("skipped")
             return False
         return True
@@ -534,12 +473,17 @@ class ShadowOracleAudit:
             "carry": ch.hexdigest(),
             "pods": int(n),
         }
+        plugins = replay_plugins(profile.framework, profile.extenders,
+                                 [q.pod for q in qpis])
         rec = AuditRecord(
             drain_id=drain_id, profile_name=profile.name,
             strategy=profile.score_config.strategy,
             weights=dict(profile.framework.weights),
             pods=[(q.pod.uid, q.pod, q.pod_info) for q in qpis],
-            nodes=nodes, framework=profile.framework,
+            nodes=nodes, plugins=plugins,
+            namespaces=(namespace_map(profile.framework)
+                        if plugins is not None else {}),
+            framework=profile.framework,
             fingerprints=fingerprints, ext_gen=ext_gen,
             captured_at=_time.time())
         return self.ledger.append(rec)
@@ -573,7 +517,7 @@ class ShadowOracleAudit:
     def submit(self, rec: AuditRecord, out, names: list, fail_msgs: dict,
                flight_rec=None, ext_gen: int = 0) -> None:
         """Record the committed device decisions and hand the record to
-        the worker (or process inline in synchronous mode)."""
+        the audit thread (or process inline in synchronous mode)."""
         import numpy as np
         device: dict = {}
         for i, (uid, _pod, _pi) in enumerate(rec.pods):
@@ -593,100 +537,118 @@ class ShadowOracleAudit:
         if self.synchronous:
             self._process(rec)
             return
-        self._ensure_worker()
-        self._queue.put(rec)
+        route = "thread" if rec.plugins is None else "process"
+        self._queues[route].put(rec)
+        self._ensure_thread(route)
 
-    # -- worker ---------------------------------------------------------------
+    # -- audit threads --------------------------------------------------------
 
-    def _ensure_worker(self) -> None:
-        if self._worker is not None and self._worker.is_alive():
-            return
-        with self._worker_lock:
-            if self._worker is not None and self._worker.is_alive():
+    def _backlog(self) -> int:
+        return sum(q.qsize() for q in self._queues.values())
+
+    def _ensure_thread(self, route: str) -> None:
+        """Start a thread for `route` while more of its records are
+        outstanding (the one just submitted included) than its threads
+        run: one on the thread route; on the process route up to
+        MAX_REPLAY_WORKERS over every audit, and always one."""
+        q = self._queues[route]
+        with self._lock:
+            threads = [t for t in self._threads[route] if t.is_alive()]
+            self._threads[route] = threads
+            cap = MAX_REPLAY_WORKERS if route == "process" else 1
+            if len(threads) >= min(cap, q.unfinished_tasks):
                 return
-            self._worker = threading.Thread(target=self._run, daemon=True,
-                                            name="shadow-oracle-audit")
-            self._worker.start()
-
-    def _run(self) -> None:
-        while True:
-            rec = self._queue.get()
-            try:
-                self._process(rec)
-            except Exception:       # the audit must never kill the worker
-                with self.ledger.lock:
-                    rec.outcome = "error"
-                self._count("error")
-            finally:
-                self._queue.task_done()
+            t = _AuditThread(weakref.ref(self), q, route)
+            with _couriers_lock:
+                if route == "process":
+                    if threads and sum(c.is_alive() for c in _couriers) \
+                            >= MAX_REPLAY_WORKERS:
+                        return
+                    _couriers.add(t)
+                t.start()
+            threads.append(t)
 
     def flush(self, timeout: float = 30.0) -> None:
         """Wait for every submitted record to finish replaying (tests,
         bench end)."""
         deadline = _time.monotonic() + timeout
         while _time.monotonic() < deadline:
-            if self._queue.unfinished_tasks == 0:
+            if all(q.unfinished_tasks == 0 for q in self._queues.values()):
                 return
             _time.sleep(0.01)
 
-    def _process(self, rec: AuditRecord) -> None:
-        """Replay + diff one record, as an `audit_replay` profiler span on
-        the thread that runs it; its CPU seconds count under
-        scheduler_thread_cpu_seconds_total{thread="audit"}."""
+    def _process(self, rec: AuditRecord, courier=None) -> None:
+        """Replay + diff + publish one record, as an `audit_replay`
+        profiler span on the thread that runs it: in `courier`'s worker
+        process when given, else on this thread through the live
+        framework. That thread's CPU seconds (the replay itself on the
+        thread route; pickling, the wait and publication on the process
+        route) count under scheduler_thread_cpu_seconds_total
+        {thread="audit"}."""
         cpu0 = _time.thread_time()
         try:
             with TraceAnnotation("audit_replay"):
-                self._replay(rec)
+                self._replay(rec, courier)
         finally:
             if self.metrics is not None:
-                self.metrics.thread_cpu_seconds.inc(
-                    "audit", by=_time.thread_time() - cpu0)
+                with self._lock:
+                    self.metrics.thread_cpu_seconds.inc(
+                        "audit", by=_time.thread_time() - cpu0)
 
-    def _replay(self, rec: AuditRecord) -> None:
+    def _replay(self, rec: AuditRecord, courier=None) -> None:
         t0 = _time.perf_counter()
+        # rec.nodes is the LEDGERED capture state — the CLI pickle and
+        # /debug re-read it pristine, and the replay works on clones of
+        # it. Only this thread replaces the list (the clear below, under
+        # the lock), so the payload's reference stays whole.
+        with self.ledger.lock:
+            payload = rec.to_payload()
+        where = "thread" if courier is None else "process"
+        if self.metrics is not None:
+            with self._lock:
+                self.metrics.shadow_audit_replays.inc(where)
+        data = None
         try:
-            # replay over fresh clones: rec.nodes is the LEDGERED capture
-            # state — the CLI pickle and /debug re-read it pristine. The
-            # clone pass is the only rec.nodes read; take it under the
-            # ledger lock so the eventual clear (below) can never race a
-            # tail subscriber or a second iteration of this list.
-            with self.ledger.lock:
-                nodes = [ni.snapshot_clone() for ni in rec.nodes]
-                device = dict(rec.device)
-                reasons_dev = dict(rec.reasons_dev)
-                reasons_ok = rec.reasons_ok
-            oracle, oracle_reasons, truncated = replay_decisions(
-                rec.framework, nodes, rec.pods, device=device,
-                cap=self.max_replay_pods)
+            if courier is not None:
+                data = pickle.dumps(payload,
+                                    protocol=pickle.HIGHEST_PROTOCOL)
+                reply = courier.replay(data, self.max_replay_pods)
+            else:
+                reply = verdict(payload, self.max_replay_pods,
+                                framework=rec.framework)
         except Exception as e:
+            reply = {"error": f"replay: {e}"}
+        if "error" in reply:
             with self.ledger.lock:
                 rec.outcome = "error"
-                rec.skip_reason = f"replay: {e}"
+                rec.skip_reason = reply["error"]
                 rec.replay_s = _time.perf_counter() - t0
             self._count("error")
             return
-        diffs = diff_decisions(
-            device, reasons_dev, oracle, oracle_reasons,
-            reasons_ok=reasons_ok and not truncated)
-        divergent = bool(diffs)
+        diffs = reply["diffs"]
+        divergent = reply["outcome"] == "divergent"
         # one atomic publication of the verdict: a tail subscriber (or
         # /debug/audit) sees either a fully "pending" record or a fully
         # replayed one — never outcome without diffs or vice versa
         with self.ledger.lock:
-            rec.replay_s = _time.perf_counter() - t0
-            rec.oracle = oracle
-            rec.reasons_oracle = oracle_reasons
-            rec.truncated = truncated
+            rec.replay_s = reply["replay_s"]
+            rec.oracle = reply.get("oracle", {})
+            rec.reasons_oracle = reply.get("reasons", {})
+            rec.truncated = reply["truncated"]
             rec.diffs = diffs
-            rec.outcome = "divergent" if divergent else "clean"
-        if self.metrics is not None:
-            for kind, items in diffs.items():
-                self.metrics.oracle_divergence.inc(kind, by=len(items))
-            self.metrics.audit_replay_duration.observe(rec.replay_s)
+            rec.outcome = reply["outcome"]
+        with self._lock:
+            if self.metrics is not None:
+                for kind, items in diffs.items():
+                    self.metrics.oracle_divergence.inc(kind, by=len(items))
+                self.metrics.audit_replay_duration.observe(rec.replay_s)
+                if where == "process":
+                    self.metrics.audit_worker_cpu_seconds.inc(
+                        by=reply["cpu_s"])
+            if self.slo is not None:
+                self.slo.observe("divergence", good=0 if divergent else 1,
+                                 bad=1 if divergent else 0)
         self._count(rec.outcome)
-        if self.slo is not None:
-            self.slo.observe("divergence", good=0 if divergent else 1,
-                             bad=1 if divergent else 0)
         flight = getattr(rec, "_flight", None)
         if flight is not None:
             flight.audit = {"outcome": rec.outcome,
@@ -694,7 +656,7 @@ class ShadowOracleAudit:
                             "diffs": diffs,
                             "hash": rec.hash}
         if self.dirpath:
-            self._persist(rec)
+            self._persist(rec, data)
         if not divergent:
             # memory bound: a clean record's replay payload (O(nodes)
             # NodeInfo clones) is no longer needed — the hash chain,
@@ -703,20 +665,24 @@ class ShadowOracleAudit:
             # persistence is on, already captured the full payload)
             with self.ledger.lock:
                 rec.nodes = []
-                rec.oracle = {}
-                rec.reasons_oracle = {}
 
     def _count(self, outcome: str) -> None:
         if self.metrics is not None:
-            self.metrics.shadow_audit_drains.inc(outcome)
+            with self._lock:
+                self.metrics.shadow_audit_drains.inc(outcome)
 
-    def _persist(self, rec: AuditRecord) -> None:
+    def _persist(self, rec: AuditRecord, data: Optional[bytes]) -> None:
+        """Write the record's payload: `data`, the bytes the worker
+        replayed, when it went there."""
         try:
+            if data is None:
+                data = pickle.dumps(rec.to_payload(),
+                                    protocol=pickle.HIGHEST_PROTOCOL)
             os.makedirs(self.dirpath, exist_ok=True)
             path = os.path.join(self.dirpath,
                                 f"drain_{rec.drain_id:08d}.pkl")
             with open(path, "wb") as f:
-                pickle.dump(rec.to_payload(), f)
+                f.write(data)
         except Exception:           # persistence is best-effort
             pass
 
@@ -726,5 +692,58 @@ class ShadowOracleAudit:
         d = self.ledger.dump(limit=limit, details=details)
         d["sampleRate"] = self.sample_rate
         d["maxReplayPods"] = self.max_replay_pods
-        d["queued"] = self._queue.qsize()
+        d["queued"] = self._backlog()
         return d
+
+
+def _end_threads(queues: dict) -> None:
+    for route, q in queues.items():
+        for _ in range(MAX_REPLAY_WORKERS if route == "process" else 1):
+            q.put(None)
+
+
+class _AuditThread(threading.Thread):
+    """An audit thread: replays the records of one route for the audit
+    that `ref` still reaches, and ends when that audit has been
+    collected. A courier (the process route) owns one worker process,
+    started at its first record and replaced when it dies."""
+
+    def __init__(self, ref, q: _queue.Queue, route: str):
+        super().__init__(daemon=True, name="shadow-oracle-audit")
+        self._ref = ref
+        self._q = q
+        self.courier = route == "process"
+        self.worker: Optional[ReplayWorker] = None
+
+    def replay(self, data: bytes, cap: int) -> dict:
+        """One pickled payload through this courier's worker process.
+        Waiting on the pipe releases the interpreter lock."""
+        if self.worker is None:
+            self.worker = ReplayWorker()
+        try:
+            return self.worker.replay(data, cap)
+        except WorkerDied:
+            self.worker.close()
+            self.worker = None
+            raise
+
+    def run(self) -> None:
+        try:
+            while True:
+                rec = self._q.get()
+                try:
+                    audit = self._ref()
+                    if audit is None or rec is None:
+                        return
+                    try:
+                        audit._process(rec, self if self.courier else None)
+                    except Exception:   # the audit must never kill the thread
+                        with audit.ledger.lock:
+                            rec.outcome = "error"
+                        audit._count("error")
+                    del audit
+                finally:
+                    self._q.task_done()
+        finally:
+            if self.worker is not None:
+                self.worker.close()

@@ -16,10 +16,11 @@ artifact, long after the process is gone:
 Exit codes: 0 = replay matches the recorded device decisions,
 2 = divergence found, 3 = unusable record.
 
-The oracle framework is rebuilt from the default plugin set with the
-recorded per-profile weights/strategy — exact for default-plugin
-profiles (custom out-of-tree plugin sets need the live scheduler's
-ledger instead).
+The replay is the live audit's own (kubernetes_tpu/obs/replay.py): the
+oracle framework is rebuilt from the default plugins the record names
+(the whole default set for a record that names none) with the recorded
+weights and namespaces — exact for default-plugin profiles (custom
+out-of-tree plugin sets need the live scheduler's ledger instead).
 """
 
 from __future__ import annotations
@@ -32,51 +33,6 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-
-
-def build_framework(profile_name: str, weights: dict):
-    from kubernetes_tpu.framework.runtime import Framework
-    from kubernetes_tpu.scheduler import DEFAULT_WEIGHTS, default_plugins
-    w = dict(DEFAULT_WEIGHTS)
-    w.update(weights or {})
-    return Framework(profile_name, default_plugins(), weights=w)
-
-
-def replay(payload: dict, cap: int = 64) -> dict:
-    from kubernetes_tpu.obs.audit import (diff_decisions, replay_decisions,
-                                          _sha)
-    fwk = build_framework(payload.get("profile", "default-scheduler"),
-                          payload.get("weights", {}))
-    nodes = [ni.snapshot_clone() for ni in payload["nodes"]]
-    oracle, oracle_reasons, truncated = replay_decisions(
-        fwk, nodes, payload["pods"], device=payload.get("device"),
-        cap=cap)
-    diffs = diff_decisions(payload.get("device", {}),
-                           payload.get("reasonsDevice", {}),
-                           oracle, oracle_reasons,
-                           reasons_ok=payload.get("reasonsOk", True)
-                           and not truncated)
-    # hash integrity: the pickle's chain entry must still hash to itself
-    chain = json.dumps({"drain": payload["drainId"],
-                        "profile": payload["profile"],
-                        "fingerprints": payload["fingerprints"]},
-                       sort_keys=True).encode()
-    hash_ok = _sha(payload.get("prevHash", ""), chain) \
-        == payload.get("hash", "")
-    return {
-        "drainId": payload["drainId"],
-        "profile": payload["profile"],
-        "pods": len(payload["pods"]),
-        "replayed": min(cap, len(payload["pods"])) if cap
-        else len(payload["pods"]),
-        "truncated": truncated,
-        "hashValid": hash_ok,
-        "fingerprints": payload["fingerprints"],
-        "oracle": {uid: (v["host"] if v else None)
-                   for uid, v in oracle.items()},
-        "diffs": diffs,
-        "divergences": sum(len(v) for v in diffs.values()),
-    }
 
 
 def main(argv=None) -> int:
@@ -92,6 +48,7 @@ def main(argv=None) -> int:
                     help="print the full machine-readable result")
     args = ap.parse_args(argv)
 
+    from kubernetes_tpu.obs.replay import replay
     try:
         with open(args.record, "rb") as f:
             payload = pickle.load(f)
@@ -99,6 +56,9 @@ def main(argv=None) -> int:
     except Exception as e:
         print(f"audit_replay: unusable record: {e}", file=sys.stderr)
         return 3
+    result["oracle"] = {uid: (v["host"] if v else None)
+                        for uid, v in result["oracle"].items()}
+    del result["reasons"]
 
     if args.json:
         print(json.dumps(result, indent=2, default=str))
