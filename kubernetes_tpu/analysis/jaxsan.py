@@ -6,11 +6,9 @@ syntactic — `if x:` is fine on the host and a trace-time crash (or a
 silently baked-in constant) on a traced value; `np.zeros(n)` is fine in
 `build_dev` and a retrace bomb inside `_run_batch_impl`. Telling the two
 apart requires (a) knowing WHICH functions execute under `jax.jit` — the
-call-graph closure of the jitted impls behind the nine public entries
-(run_batch, run_uniform, run_wave, run_wave_scan, wave_statics,
-diagnose_row, dry_run_select_victims, run_batch_sharded; the same set the
-compile ledger wraps) — and (b) knowing WHICH values are traced inside
-them — `fam` is a static argname and `if fam.spr_f:` is the intended
+call-graph closure of the jitted impls behind the public entries (every
+function declared with `@device_entry`, perf/ledger.py) — and (b) knowing
+WHICH values are traced inside them — `fam` is a static argname and `if fam.spr_f:` is the intended
 kernel-trimming idiom, while the same branch on `mask` would be a bug.
 
 The analyzer therefore does a light interprocedural dataflow:
@@ -48,35 +46,10 @@ from dataclasses import dataclass, field
 
 from .findings import Finding, RULES
 
-# the ten public JIT entries (perf/ledger.py KERNELS wraps the same
-# set); tools/check.py asserts each one resolves to at least one
-# discovered jit root, so the lint cannot silently lose coverage.
-# run_plan is the drain compiler's program (kubernetes_tpu/compiler/
-# emits DrainPlans whose wavescan spans dispatch it).
-ENTRY_POINTS = {
-    "kubernetes_tpu.ops.program": (
-        "run_batch", "run_uniform", "run_wave", "run_wave_scan",
-        "run_plan", "wave_statics", "diagnose_row",
-        "dry_run_select_victims", "scatter_rows", "explain_row",
-        "cluster_probe"),
-    "kubernetes_tpu.ops.gang": ("run_gang",),
-    "kubernetes_tpu.parallel.sharding": (
-        "run_batch_sharded", "run_uniform_sharded", "run_plan_sharded",
-        "run_gang_sharded", "scatter_rows_sharded",
-        "cluster_probe_sharded"),
-}
-
-# public entries that DONATE an argument's buffers to the compiled
-# program (ops/program.py donate_argnums factories): callers must never
-# read the donated variable after the call. Param index is the position
-# of the donated argument in the PUBLIC entry's signature.
-DONATING_ENTRIES = {
-    "run_batch": (2, "carry"),
-    "run_wave": (2, "carry"),
-    "run_wave_scan": (2, "carry"),
-    "run_plan": (2, "carry"),
-    "run_gang": (2, "carry"),
-}
+# the decorator that declares a device entry (perf/ledger.py): every
+# function it decorates is an entry point, and its `donates=` names the
+# argument the entry donates
+ENTRY_DECORATOR = "device_entry"
 
 # attribute reads that always yield host-static values, even on tracers
 _STATIC_ATTRS = {"shape", "ndim", "dtype", "size", "_fields"}
@@ -168,10 +141,11 @@ class JaxsanAnalyzer:
                  donating: dict | None = None):
         self.root = root
         self.package = package
-        self.entry_points = (ENTRY_POINTS if entry_points is None
-                             else entry_points)
-        self.donating = (DONATING_ENTRIES if donating is None
-                         else donating)
+        # None = the `@device_entry` declarations found by load()
+        self.entry_points = entry_points
+        self.donating = donating
+        # qualname → (kernel label, donated position) per declaration
+        self.declared: dict[str, tuple] = {}
         self.modules: dict[str, ModuleInfo] = {}
         self.fns: dict[str, FnInfo] = {}          # qualname → FnInfo
         self.findings: list[Finding] = []
@@ -204,6 +178,7 @@ class JaxsanAnalyzer:
                                                tree=tree, source=source)
         for mi in self.modules.values():
             self._index_module(mi)
+        self._discover_entries()
         self._discover_roots()
         self._propagate()
         return self
@@ -357,6 +332,35 @@ class JaxsanAnalyzer:
             if p.arg == name:
                 return p.annotation
         return None
+
+    def _discover_entries(self) -> None:
+        """Read every `@device_entry(...)` declaration; unless the caller
+        gave them, they are the entry points and the donating entries
+        (donated param index and name in the public signature)."""
+        entries: dict[str, list] = {}
+        donating: dict[str, tuple] = {}
+        for fi in self.fns.values():
+            for dec in fi.node.decorator_list:
+                if not isinstance(dec, ast.Call) or (
+                        _dotted(dec.func) or "").split(".")[-1] \
+                        != ENTRY_DECORATOR:
+                    continue
+                label = fi.name
+                if dec.args and isinstance(dec.args[0], ast.Constant):
+                    label = dec.args[0].value
+                donates = None
+                for kw in dec.keywords:
+                    if kw.arg == "donates" and isinstance(
+                            kw.value, ast.Constant):
+                        donates = kw.value.value
+                self.declared[fi.qualname] = (label, donates)
+                entries.setdefault(fi.module.name, []).append(fi.name)
+                if donates is not None:
+                    donating[fi.name] = (donates, fi.params()[donates])
+        if self.entry_points is None:
+            self.entry_points = {m: tuple(ns) for m, ns in entries.items()}
+        if self.donating is None:
+            self.donating = donating
 
     def _discover_roots(self) -> None:
         for mi in self.modules.values():

@@ -89,6 +89,6 @@ class SurfaceCache:
 
     def stacked(self, na, table, rows: tuple) -> tuple:
         """Surfaces for `rows` stacked into ([S, N], ...) — the layout
-        run_plan / run_wave_scan / run_gang consume."""
+        run_plan / run_gang consume."""
         per_row = self.get(na, table, rows)
         return tuple(jnp.stack([r[f] for r in per_row]) for f in range(4))

@@ -805,8 +805,8 @@ class SchedulerMetrics:
             self.plugin_execution_duration.seed(plugin, "Score", "SUCCESS")
             self.plugin_evaluation_total.inc(plugin, "Score",
                                              DEFAULT_PROFILE, by=0)
-        from ..perf.ledger import H2D_PHASES, KERNELS
-        for kernel in KERNELS:
+        from ..perf.ledger import H2D_PHASES, device_entries
+        for kernel in device_entries():
             self.xla_compiles.inc(kernel, by=0)
             self.xla_compile_seconds.inc(kernel, by=0)
             self.kernel_device_seconds.inc(kernel, by=0)

@@ -46,8 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..analysis.rails import GLOBAL as RAILS
-from ..perf.ledger import GLOBAL as LEDGER
+from ..perf.ledger import device_entry
 from ..state.tensorize import NodeArrays
 from .program import (Carry, PodTableDev, PodXs, ScoreConfig, _fit_scores,
                       _gather_row, _uniform_core, balanced_allocation,
@@ -218,6 +217,7 @@ def _run_gang_uniform_jit(cfg: ScoreConfig, na: NodeArrays, carry: Carry,
     return carry_out, packed
 
 
+@device_entry(donates=2, cost=(64.0, 4.0))
 def run_gang(cfg: ScoreConfig, na: NodeArrays, carry: Carry, xs, table,
              wt=None, needed=None, dom=None, statics=None,
              w_contig: int = 0, uniform: bool = False, n_actual=None,
@@ -239,18 +239,7 @@ def run_gang(cfg: ScoreConfig, na: NodeArrays, carry: Carry, xs, table,
     quorum (minCount minus already-assigned members), a dynamic i32 so
     quorum values never mint executables."""
     if uniform:
-        na, carry, xs, table, n_actual, needed = RAILS.stage(
-            (na, carry, xs, table, n_actual, needed))
-        return LEDGER.measured_call("run_gang", _run_gang_uniform_jit, cfg,
-                                    na, carry, xs, table, n_actual, needed,
-                                    L, K, J)
-    donate = jax.default_backend() != "cpu"
-    fn = _run_gang_scan_fn(donate)
-    na, carry, xs, table, wt, needed, dom, statics = RAILS.stage(
-        (na, carry, xs, table, wt, needed, dom, statics))
-    out = LEDGER.measured_call("run_gang", fn, cfg, na, carry, xs, table,
-                               wt, needed, dom, statics, w_contig,
-                               donated=carry if donate else None)
-    if not donate:
-        RAILS.poison_donated(carry, out)
-    return out
+        return _run_gang_uniform_jit, (cfg, na, carry, xs, table, n_actual,
+                                       needed, L, K, J)
+    return _run_gang_scan_fn, (cfg, na, carry, xs, table, wt, needed, dom,
+                               statics, w_contig)

@@ -43,17 +43,10 @@ from ..state.batch import (OP_DOES_NOT_EXIST, OP_EXISTS, OP_GT, OP_IN,
                            OP_LT, OP_NOT_IN, TOL_EQUAL, TOL_EXISTS)
 from ..state.tensorize import (EFFECT_NO_EXECUTE, EFFECT_NO_SCHEDULE,
                                EFFECT_PREFER_NO_SCHEDULE, NodeArrays)
-# compile ledger (perf/ledger.py): every public jit entry below dispatches
-# through LEDGER.measured_call so fresh compiles/retraces/donation misses
-# are attributed per kernel (scheduler_xla_compiles_total{kernel})
-from ..perf.ledger import GLOBAL as LEDGER
-# sanitizer rails (analysis/rails.py, `SanitizerRails` gate): with rails
-# active, every entry explicitly stages its host-side array args
-# (device_put — the declared escape under jax.transfer_guard) and the
-# donating entries poison the consumed carry on backends that compiled
-# without donation (CPU), so use-after-donate raises here instead of
-# corrupting state on a real accelerator
-from ..analysis.rails import GLOBAL as RAILS
+# every public jit entry below is a `device_entry` (perf/ledger.py): its
+# dispatches are staged through the sanitizer rails and measured by the
+# compile ledger under its kernel label
+from ..perf.ledger import GLOBAL as LEDGER, device_entry
 
 MAX_SCORE = 100
 
@@ -625,6 +618,7 @@ def _diagnose_lean(na: NodeArrays, table: PodTableDev, tidx):
     return _diagnose_masks(na, pod, None, None, tidx, None)
 
 
+@device_entry("diagnose", cost=(16.0, 2.0))
 def diagnose_row(na: NodeArrays, table: PodTableDev, tidx: int,
                  gd=None, gc=None, fam=None):
     """Reduce the filter masks of signature row `tidx` against node state
@@ -634,12 +628,8 @@ def diagnose_row(na: NodeArrays, table: PodTableDev, tidx: int,
     carry the per-reason detail for DIAG_FIT nodes ("Too many pods" /
     per-column Insufficient)."""
     if gd is not None:
-        na, table, gd, gc = RAILS.stage((na, table, gd, gc))
-        return LEDGER.measured_call("diagnose", _diagnose_groups, na, table,
-                                    jnp.int32(tidx), gd, gc, fam)
-    na, table = RAILS.stage((na, table))
-    return LEDGER.measured_call("diagnose", _diagnose_lean, na, table,
-                                jnp.int32(tidx))
+        return _diagnose_groups, (na, table, jnp.int32(tidx), gd, gc, fam)
+    return _diagnose_lean, (na, table, jnp.int32(tidx))
 
 
 # ---------------------------------------------------------------------------
@@ -698,6 +688,7 @@ def _explain_lean(cfg: ScoreConfig, na: NodeArrays, carry: Carry,
     return _explain_masks(cfg, na, carry, tidx, k, table, None, None)
 
 
+@device_entry(cost=(16.0, 2.0))
 def explain_row(cfg: ScoreConfig, na: NodeArrays, carry: Carry,
                 table: PodTableDev, tidx: int, k: int = 8, gd=None,
                 fam=None):
@@ -709,14 +700,9 @@ def explain_row(cfg: ScoreConfig, na: NodeArrays, carry: Carry,
     at this carry (same _eval_pod formula, same tie-break); the win
     margin is topk_total[0] - topk_total[1]."""
     if gd is not None:
-        na, carry, table = RAILS.stage((na, carry, table))
-        gd = RAILS.stage(gd)
-        return LEDGER.measured_call("explain_row", _explain_groups, cfg,
-                                    na, carry, table, jnp.int32(tidx), k,
-                                    gd, fam)
-    na, carry, table = RAILS.stage((na, carry, table))
-    return LEDGER.measured_call("explain_row", _explain_lean, cfg, na,
-                                carry, table, jnp.int32(tidx), k)
+        return _explain_groups, (cfg, na, carry, table, jnp.int32(tidx), k,
+                                 gd, fam)
+    return _explain_lean, (cfg, na, carry, table, jnp.int32(tidx), k)
 
 
 @jax.jit
@@ -724,6 +710,7 @@ def _scatter_rows_jit(dev: NodeArrays, idx, rows: NodeArrays) -> NodeArrays:
     return NodeArrays(*(d.at[idx].set(r) for d, r in zip(dev, rows)))
 
 
+@device_entry(cost=(2.0, 2.0))
 def scatter_rows(dev: NodeArrays, idx, rows: NodeArrays) -> NodeArrays:
     """Generation-diff snapshot upload (ISSUE 9): scatter `rows` (one
     gathered staging row per dirty node, [D, ...] with D a pow2 bucket;
@@ -735,9 +722,7 @@ def scatter_rows(dev: NodeArrays, idx, rows: NodeArrays) -> NodeArrays:
     callers (in-flight drains hold it as `pd.na`; tests hold it across
     mutations), so the entry must materialize fresh output buffers — an
     on-device copy in place of a full host-to-device re-upload."""
-    dev, idx, rows = RAILS.stage((dev, idx, rows))
-    return LEDGER.measured_call("scatter_rows", _scatter_rows_jit, dev,
-                                idx, rows)
+    return _scatter_rows_jit, (dev, idx, rows)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -765,14 +750,14 @@ def _score_probe_jit(cfg: ScoreConfig, na: NodeArrays, carry: Carry,
     return total, std.astype(jnp.float32)
 
 
+@device_entry(cost=(16.0, 2.0))
 def score_probe(cfg: ScoreConfig, na: NodeArrays, carry: Carry,
                 table: PodTableDev, tidx):
     """Score surface of signature row `tidx` against `carry`, in float:
     (combined fit+balanced score f32 [N], balanced-allocation std f32
     [N]). The sanitizer rails' NaN/inf probe (analysis/rails.py
     check_scores) — one tiny shape-stable kernel per drain."""
-    return LEDGER.measured_call("score_probe", _score_probe_jit, cfg, na,
-                                carry, table, tidx)
+    return _score_probe_jit, (cfg, na, carry, table, tidx)
 
 
 # ---------------------------------------------------------------------------
@@ -891,6 +876,7 @@ def _cluster_probe_jit(na: NodeArrays, carry: Carry, dom, ndom: int):
                        ndom)
 
 
+@device_entry(cost=(24.0, 2.0))
 def cluster_probe(na: NodeArrays, carry: Carry, dom, ndom: int):
     """On-device cluster-state reduction over the resident carry:
     (per_res f32 [R, 7] — PROBE_STATS columns per resource, dom_stats
@@ -899,9 +885,7 @@ def cluster_probe(na: NodeArrays, carry: Carry, dom, ndom: int):
     dom-id column (i32 [N]), `ndom` its static domain count (jit cache
     key — stable per cluster topology). Deliberately NON-donating: the
     carry stays resident for the next drain; the probe only reads it."""
-    na, carry, dom = RAILS.stage((na, carry, dom))
-    return LEDGER.measured_call("cluster_probe", _cluster_probe_jit, na,
-                                carry, dom, ndom)
+    return _cluster_probe_jit, (na, carry, dom, ndom)
 
 
 def _apply_assignment(carry: Carry, pod: PodRow, best: jnp.ndarray,
@@ -982,6 +966,7 @@ def _run_batch_fn(donate: bool):
                    donate_argnums=(2,) if donate else ())
 
 
+@device_entry(donates=2, cost=(48.0, 3.0))
 def run_batch(cfg: ScoreConfig, na: NodeArrays, carry: Carry, pods: PodXs,
               table: PodTableDev, groups: GroupsDev | None = None,
               fam: GroupFamilies | None = None, overlay=None):
@@ -993,16 +978,8 @@ def run_batch(cfg: ScoreConfig, na: NodeArrays, carry: Carry, pods: PodXs,
     replay (the uniform path's exactness fallback) must therefore never
     reuse a carry already consumed by run_batch — the scheduler keeps
     carry_in only for run_uniform records, which do not donate."""
-    donate = jax.default_backend() != "cpu"
-    fn = _run_batch_fn(donate)
-    na, carry, pods, table, groups, overlay = RAILS.stage(
-        (na, carry, pods, table, groups, overlay))
-    out = LEDGER.measured_call("run_batch", fn, cfg, na, carry, pods,
-                               table, groups, fam, overlay,
-                               donated=carry if donate else None)
-    if not donate:
-        RAILS.poison_donated(carry, out)
-    return out
+    return _run_batch_fn, (cfg, na, carry, pods, table, groups, fam,
+                           overlay)
 
 
 def _uniform_matrix(cfg: ScoreConfig, na: NodeArrays, fit_used, fit_npods,
@@ -1204,17 +1181,15 @@ def _run_uniform_jit(cfg: ScoreConfig, na: NodeArrays, carry: Carry, x: PodXs,
     return new_carry, packed
 
 
+@device_entry(cost=(32.0, 3.0))
 def run_uniform(cfg: ScoreConfig, na: NodeArrays, carry: Carry, x: PodXs,
                 table: PodTableDev, n_actual, L: int, K: int, J: int,
                 overlay=None):
     """Ledger-instrumented entry for `_run_uniform_jit` (the closed-form
     top-L path; see its docstring for the exactness argument). Never
     donates: the scheduler keeps the input carry for rewind/replay."""
-    na, carry, x, table, n_actual, overlay = RAILS.stage(
-        (na, carry, x, table, n_actual, overlay))
-    return LEDGER.measured_call("run_uniform", _run_uniform_jit, cfg, na,
-                                carry, x, table, n_actual, L, K, J,
-                                overlay=overlay)
+    return _run_uniform_jit, (cfg, na, carry, x, table, n_actual, L, K, J,
+                              overlay)
 
 
 # ---------------------------------------------------------------------------
@@ -1256,7 +1231,7 @@ class _WaveState(NamedTuple):
     ports: jnp.ndarray = None  # i32 [N, P]
 
 
-def _run_wave_scan_impl(cfg: ScoreConfig, na: NodeArrays, carry: Carry,
+def _run_plan_impl(cfg: ScoreConfig, na: NodeArrays, carry: Carry,
                         xs: WaveXs, table: PodTableDev, wt, gd: GroupsDev,
                         statics, fam: GroupFamilies, norm_live: bool,
                         has_groups: bool, has_ports: bool = False):
@@ -1560,51 +1535,14 @@ def _run_wave_scan_impl(cfg: ScoreConfig, na: NodeArrays, carry: Carry,
 
 
 @functools.lru_cache(maxsize=None)
-def _run_wave_scan_fn(donate: bool):
-    return jax.jit(_run_wave_scan_impl,
-                   static_argnames=("cfg", "fam", "norm_live", "has_groups",
-                                    "has_ports"),
-                   donate_argnums=(2,) if donate else ())
-
-
-def run_wave_scan(cfg: ScoreConfig, na: NodeArrays, carry: Carry, xs: WaveXs,
-                  table: PodTableDev, wt, gd: GroupsDev, statics,
-                  fam: GroupFamilies, norm_live: bool,
-                  has_groups: bool = True):
-    """Jitted entry for `_run_wave_scan_impl`. The input carry is DONATED on
-    accelerator backends (the chain consumes it; donation frees the old
-    buffers without a device round trip); CPU has no donation support, so
-    the CPU variant compiles without it to avoid per-dispatch warnings.
-    `statics` is wave_statics(na, table, wt) ([S, N] each), cached by the
-    scheduler per signature set. `has_groups=False` compiles the LEAN
-    variant — no group state at all (gd may be None) — for drains of
-    non-interacting signatures whose alternation would thrash the scan's
-    one-slot signature cache."""
-    donate = jax.default_backend() != "cpu"
-    fn = _run_wave_scan_fn(donate)
-    na, carry, xs, table, wt, gd, statics = RAILS.stage(
-        (na, carry, xs, table, wt, gd, statics))
-    out = LEDGER.measured_call("run_wave_scan", fn, cfg, na, carry, xs,
-                               table, wt, gd, statics, fam, norm_live,
-                               has_groups,
-                               donated=carry if donate else None)
-    if not donate:
-        RAILS.poison_donated(carry, out)
-    return out
-
-
-@functools.lru_cache(maxsize=None)
 def _run_plan_fn(donate: bool):
-    # a DISTINCT jit object over the shared wave-scan impl: the compile
-    # ledger attributes the drain compiler's plan executables to
-    # "run_plan", so the plan lattice's fixed retrace point is provable
-    # separately from the legacy run_wave_scan entry
-    return jax.jit(_run_wave_scan_impl,
+    return jax.jit(_run_plan_impl,
                    static_argnames=("cfg", "fam", "norm_live", "has_groups",
                                     "has_ports"),
                    donate_argnums=(2,) if donate else ())
 
 
+@device_entry(donates=2, cost=(48.0, 3.0))
 def run_plan(cfg: ScoreConfig, na: NodeArrays, carry: Carry, xs: WaveXs,
              table: PodTableDev, wt, gd: GroupsDev | None, statics,
              fam: GroupFamilies, norm_live: bool, has_groups: bool = True,
@@ -1618,17 +1556,8 @@ def run_plan(cfg: ScoreConfig, na: NodeArrays, carry: Carry, xs: WaveXs,
     additionally maintain the ports carry so sig-0 rows no longer force
     a span split. The input carry is DONATED on accelerator backends
     (run_batch's contract); CPU compiles without donation."""
-    donate = jax.default_backend() != "cpu"
-    fn = _run_plan_fn(donate)
-    na, carry, xs, table, wt, gd, statics = RAILS.stage(
-        (na, carry, xs, table, wt, gd, statics))
-    out = LEDGER.measured_call("run_plan", fn, cfg, na, carry, xs, table,
-                               wt, gd, statics, fam, norm_live, has_groups,
-                               has_ports,
-                               donated=carry if donate else None)
-    if not donate:
-        RAILS.poison_donated(carry, out)
-    return out
+    return _run_plan_fn, (cfg, na, carry, xs, table, wt, gd, statics, fam,
+                          norm_live, has_groups, has_ports)
 
 
 @functools.partial(jax.jit, static_argnames=("feats",))
@@ -1673,12 +1602,11 @@ def _wave_statics_jit(na: NodeArrays, table: PodTableDev, wt,
     return jax.vmap(one)(rows)
 
 
+@device_entry(cost=(8.0, 2.0))
 def wave_statics(na: NodeArrays, table: PodTableDev, wt,
                  feats: tuple = (True, True, True)):
     """Ledger-instrumented entry for `_wave_statics_jit`."""
-    na, table, wt = RAILS.stage((na, table, wt))
-    return LEDGER.measured_call("wave_statics", _wave_statics_jit, na,
-                                table, wt, feats)
+    return _wave_statics_jit, (na, table, wt, feats)
 
 
 class _SameWaveState(NamedTuple):
@@ -2038,6 +1966,7 @@ def _run_wave_same_fn(donate: bool):
                    donate_argnums=(2,) if donate else ())
 
 
+@device_entry(donates=2, cost=(64.0, 4.0))
 def run_wave(cfg: ScoreConfig, na: NodeArrays, carry: Carry, valid,
              table: PodTableDev, wt, gd: GroupsDev, statics, K: int, J: int,
              fam: GroupFamilies, norm_live: bool, anti_term: int = -1,
@@ -2047,18 +1976,10 @@ def run_wave(cfg: ScoreConfig, na: NodeArrays, carry: Carry, valid,
     signature's wave_statics row ([N] each); `Lw` caps the speculated
     entries per merge wave (span-length independent, so one executable
     serves every drain size)."""
-    donate = jax.default_backend() != "cpu"
-    fn = _run_wave_same_fn(donate)
     Lw = min(Lw, valid.shape[0])
-    na, carry, valid, table, wt, gd, statics = RAILS.stage(
-        (na, carry, valid, table, wt, gd, statics))
-    out = LEDGER.measured_call("run_wave", fn, cfg, na, carry, valid,
-                               table, wt, gd, statics, K, J, Lw, fam,
-                               norm_live, anti_term, merge_on,
-                               donated=carry if donate else None)
-    if not donate:
-        RAILS.poison_donated(carry, out)
-    return out
+    return _run_wave_same_fn, (cfg, na, carry, valid, table, wt, gd,
+                               statics, K, J, Lw, fam, norm_live, anti_term,
+                               merge_on)
 
 
 # ---------------------------------------------------------------------------
@@ -2166,16 +2087,14 @@ def _dry_run_select_victims_jit(na: NodeArrays, pod: PodRow, cand,
     return jnp.concatenate([fits[:, None], reprieved.T], axis=1)
 
 
+@device_entry("dry_run", cost=(40.0, 3.0))
 def dry_run_select_victims(na: NodeArrays, pod: PodRow, cand,
                            victim_req, victim_valid, ovl_used, ovl_npods,
                            spread: DryRunSpread | None = None):
     """Ledger-instrumented entry for `_dry_run_select_victims_jit`."""
-    (na, pod, cand, victim_req, victim_valid, ovl_used, ovl_npods,
-     spread) = RAILS.stage((na, pod, cand, victim_req, victim_valid,
-                            ovl_used, ovl_npods, spread))
-    return LEDGER.measured_call("dry_run", _dry_run_select_victims_jit,
-                                na, pod, cand, victim_req, victim_valid,
-                                ovl_used, ovl_npods, spread)
+    return _dry_run_select_victims_jit, (na, pod, cand, victim_req,
+                                         victim_valid, ovl_used, ovl_npods,
+                                         spread)
 
 
 def initial_carry(na: NodeArrays, groups: GroupCarry | None = None) -> Carry:

@@ -47,6 +47,7 @@ from ..ops.program import (MAX_SCORE, Carry, PodRow, PodTableDev, PodXs,
                            _uniform_matrix, _WaveState, balanced_allocation,
                            default_normalize, fit_mask, least_allocated,
                            ports_mask)
+from ..perf.ledger import GLOBAL as LEDGER, device_entry
 from ..state.tensorize import NodeArrays
 
 NODE_AXIS = "nodes"
@@ -185,6 +186,7 @@ def _run_batch_sharded_jit(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
     return fn(na, carry, pods, table, groups)
 
 
+@device_entry(cost=(48.0, 4.0))
 def run_batch_sharded(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
                       carry: Carry, pods: PodXs, table: PodTableDev,
                       groups: GroupsDev | None = None, fam=None):
@@ -195,12 +197,8 @@ def run_batch_sharded(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
     mesh path runs under the sanitizer rails' ambient transfer guard
     too (ISSUE 10 satellite: run_batch_sharded was the only JIT entry
     outside the rails/ledger coverage)."""
-    from ..analysis.rails import GLOBAL as RAILS
-    from ..perf.ledger import GLOBAL as LEDGER
-    pods, table = RAILS.stage((pods, table))
-    return LEDGER.measured_call("run_batch_sharded", _run_batch_sharded_jit,
-                                cfg, mesh, na, carry, pods, table, groups,
-                                fam)
+    return _run_batch_sharded_jit, (cfg, mesh, na, carry, pods, table,
+                                    groups, fam)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "fam"))
@@ -338,7 +336,6 @@ def _note_shard_upload(phase: str, tree) -> None:
     """Attribute a mesh placement's H2D bytes to its drain phase — the
     same `scheduler_h2d_bytes_total{phase}` surface the single-device
     uploads report through (perf/ledger.py)."""
-    from ..perf.ledger import GLOBAL as LEDGER
     LEDGER.note_h2d_tree(phase, tree)
 
 
@@ -533,6 +530,7 @@ def _run_uniform_sharded_jit(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
     return new_carry, packed
 
 
+@device_entry(cost=(32.0, 4.0))
 def run_uniform_sharded(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
                         carry: Carry, x: PodXs, table: PodTableDev,
                         n_actual, L: int, K: int, J: int):
@@ -543,12 +541,8 @@ def run_uniform_sharded(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
     Packed layout identical to run_uniform ([assignments(L); exact;
     depth]); never donates — the scheduler keeps the input carry to
     replay failed exactness preconditions on the sharded scan."""
-    from ..analysis.rails import GLOBAL as RAILS
-    from ..perf.ledger import GLOBAL as LEDGER
-    x, table, n_actual = RAILS.stage((x, table, n_actual))
-    return LEDGER.measured_call("run_uniform_sharded",
-                                _run_uniform_sharded_jit, cfg, mesh, na,
-                                carry, x, table, n_actual, L, K, J)
+    return _run_uniform_sharded_jit, (cfg, mesh, na, carry, x, table,
+                                      n_actual, L, K, J)
 
 
 # ---------------------------------------------------------------------------
@@ -558,7 +552,7 @@ def run_uniform_sharded(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
 def _plan_local(cfg: ScoreConfig, n_global: int, fam, norm_live: bool,
                 has_groups: bool, has_ports: bool, na_l: NodeArrays,
                 carry_l: Carry, xs, table: PodTableDev, wt, gd_l, statics_l):
-    """SPMD body of `run_plan_sharded` — `ops.program._run_wave_scan_impl`
+    """SPMD body of `run_plan_sharded` — `ops.program._run_plan_impl`
     with the node axis local: per-signature surfaces and group counters
     hold one shard, the per-step argmax is the pmax/pmin global
     tie-break, and every "chosen node's row" read becomes an
@@ -859,6 +853,7 @@ def _run_plan_sharded_jit(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
     return fn(na, carry, xs, table, wt, gd, statics)
 
 
+@device_entry(cost=(48.0, 4.0))
 def run_plan_sharded(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
                      carry: Carry, xs, table: PodTableDev, wt,
                      gd: GroupsDev | None, statics, fam, norm_live: bool,
@@ -869,13 +864,9 @@ def run_plan_sharded(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
     repair as the single-device plan program); never donates — the mesh
     carry stays resident across the drain. `statics` are the
     SurfaceCache's [S, N] stacks, node axis sharded P(None, nodes)."""
-    from ..analysis.rails import GLOBAL as RAILS
-    from ..perf.ledger import GLOBAL as LEDGER
-    xs, table, wt, statics = RAILS.stage((xs, table, wt, statics))
-    return LEDGER.measured_call("run_plan_sharded", _run_plan_sharded_jit,
-                                cfg, mesh, na, carry, xs, table, wt, gd,
-                                statics, fam, norm_live, has_groups,
-                                has_ports)
+    return _run_plan_sharded_jit, (cfg, mesh, na, carry, xs, table, wt, gd,
+                                   statics, fam, norm_live, has_groups,
+                                   has_ports)
 
 
 # ---------------------------------------------------------------------------
@@ -1066,6 +1057,7 @@ def _run_gang_uniform_sharded_jit(cfg: ScoreConfig, mesh: Mesh,
     return carry_out, packed
 
 
+@device_entry(cost=(64.0, 5.0))
 def run_gang_sharded(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
                      carry: Carry, xs, table: PodTableDev, wt=None,
                      needed=None, dom=None, statics=None, w_contig: int = 0,
@@ -1076,21 +1068,12 @@ def run_gang_sharded(cfg: ScoreConfig, mesh: Mesh, na: NodeArrays,
     donates: the scheduler keeps the input carry to replay failed
     uniform-tier preconditions on the scan tier, and the reject unwind
     is on-device on every shard."""
-    from ..analysis.rails import GLOBAL as RAILS
-    from ..perf.ledger import GLOBAL as LEDGER
     if uniform:
-        x, table, n_actual, needed = RAILS.stage(
-            (xs, table, n_actual, needed))
-        return LEDGER.measured_call("run_gang_sharded",
-                                    _run_gang_uniform_sharded_jit, cfg,
-                                    mesh, na, carry, x, table, n_actual,
-                                    needed, L, K, J)
-    xs, table, wt, needed, statics = RAILS.stage(
-        (xs, table, wt, needed, statics))
-    return LEDGER.measured_call("run_gang_sharded",
-                                _run_gang_scan_sharded_jit, cfg, mesh, na,
-                                carry, xs, table, wt, needed, dom, statics,
-                                w_contig)
+        return _run_gang_uniform_sharded_jit, (cfg, mesh, na, carry, xs,
+                                               table, n_actual, needed, L,
+                                               K, J)
+    return _run_gang_scan_sharded_jit, (cfg, mesh, na, carry, xs, table, wt,
+                                        needed, dom, statics, w_contig)
 
 
 # ---------------------------------------------------------------------------
@@ -1126,6 +1109,7 @@ def _scatter_rows_sharded_jit(mesh: Mesh, dev: NodeArrays, idx,
     return fn(dev, idx, rows)
 
 
+@device_entry(cost=(2.0, 3.0))
 def scatter_rows_sharded(mesh: Mesh, dev: NodeArrays, idx,
                          rows: NodeArrays) -> NodeArrays:
     """Scatter `rows` (replicated, [B, ...] per leaf) into the resident
@@ -1133,14 +1117,8 @@ def scatter_rows_sharded(mesh: Mesh, dev: NodeArrays, idx,
     repeating a real index — duplicate writes carry identical values).
     Each shard keeps only its own rows; the H2D bytes are the small
     replicated row block, not the full matrix."""
-    from ..analysis.rails import GLOBAL as RAILS
-    from ..perf.ledger import GLOBAL as LEDGER
-    idx, rows = RAILS.stage((idx, rows))
-    out = LEDGER.measured_call("scatter_rows_sharded",
-                               _scatter_rows_sharded_jit, mesh, dev, idx,
-                               rows)
     _note_shard_upload("host_snapshot", rows)
-    return out
+    return _scatter_rows_sharded_jit, (mesh, dev, idx, rows)
 
 
 # ---------------------------------------------------------------------------
@@ -1190,6 +1168,7 @@ def _cluster_probe_sharded_jit(mesh: Mesh, na: NodeArrays, carry: Carry,
     return fn(na.cap, na.valid, carry.used, carry.npods, dom)
 
 
+@device_entry(cost=(24.0, 3.0))
 def cluster_probe_sharded(mesh: Mesh, na: NodeArrays, carry: Carry, dom,
                           ndom: int):
     """`ops.program.cluster_probe`'s mesh twin: all-gathers the node
@@ -1198,9 +1177,4 @@ def cluster_probe_sharded(mesh: Mesh, na: NodeArrays, carry: Carry, dom,
     bit-identical to the single-device probe (tests/test_cluster_probe.py
     oracle transitively holds). `dom` is replicated; the carry and node
     arrays stay resident shards — zero extra h2d, like the original."""
-    from ..analysis.rails import GLOBAL as RAILS
-    from ..perf.ledger import GLOBAL as LEDGER
-    na, carry, dom = RAILS.stage((na, carry, dom))
-    return LEDGER.measured_call("cluster_probe_sharded",
-                                _cluster_probe_sharded_jit, mesh, na,
-                                carry, dom, ndom)
+    return _cluster_probe_sharded_jit, (mesh, na, carry, dom, ndom)

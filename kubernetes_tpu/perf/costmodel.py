@@ -11,11 +11,11 @@ that cost table:
   asks XLA for the variant's cost via `Lowered.cost_analysis()` —
   tracing + lowering only, never a second XLA compile (a few ms for a
   small program on the CPU, paid only on compile events) — and falls
-  back to a per-kernel HOST ESTIMATOR (`KERNEL_COSTS` coefficients over
-  the args' array cells/bytes) where XLA reports nothing (on the TPU,
-  `cost_analysis()` is None for the sharded programs). Every jaxsan ENTRY_POINT's kernel MUST have a
-  `KERNEL_COSTS` entry: tools/check.py `cost_model_gaps` (exit 2)
-  mirrors `observatory_gaps`, so a new JIT entry cannot land uncosted.
+  back to a per-kernel HOST ESTIMATOR (the `cost` coefficients of the
+  kernel's `device_entry` declaration, perf/ledger.py, over the args'
+  array cells/bytes) where XLA reports nothing (on the TPU,
+  `cost_analysis()` is None for the sharded programs). The declaration
+  requires the coefficients, so no device entry lands uncosted.
 - per (flops, bytes) row the model derives arithmetic intensity and —
   against the device kind's roofline anchors (`PEAKS`) — a modeled runtime
   `max(flops/peak_flops, bytes/peak_bw)`, the achieved-vs-modeled
@@ -36,6 +36,8 @@ import threading
 
 import numpy as np
 
+from .ledger import device_entries
+
 # Roofline anchors per device kind (`jax.devices()[0].device_kind`):
 # (peak flops/s, peak bytes/s). A device kind missing here is an error,
 # not a default: a roofline against the wrong chip's peaks is a wrong
@@ -47,36 +49,6 @@ PEAKS = {
     # XLA:CPU (the test sandbox): a coarse single-socket anchor, ~100
     # GFLOP/s and ~20 GB/s — used only to rank variants in CPU tests
     "cpu": (1.0e11, 2.0e10),
-}
-
-# Host-estimator coefficients per ledger kernel: (flops per array cell,
-# bytes-accessed multiplier over the args' raw bytes). The flops
-# coefficients encode each kernel's work shape — the scoring/filter
-# kernels do a few tens of ops per node-pod cell, the scan/wave kernels
-# revisit the carry per segment, the probe/diagnose reductions are
-# single-pass. Used ONLY where XLA's cost_analysis reports nothing;
-# rows carry source="host" so readers know the provenance.
-# tools/check.py cost_model_gaps asserts every ENTRY_KERNELS target has
-# an entry here.
-KERNEL_COSTS = {
-    "run_batch": (48.0, 3.0),
-    "run_uniform": (32.0, 3.0),
-    "run_wave": (64.0, 4.0),
-    "run_wave_scan": (96.0, 5.0),
-    "run_plan": (48.0, 3.0),
-    "wave_statics": (8.0, 2.0),
-    "diagnose": (16.0, 2.0),
-    "dry_run": (40.0, 3.0),
-    "run_gang": (64.0, 4.0),
-    "scatter_rows": (2.0, 2.0),
-    "explain_row": (16.0, 2.0),
-    "cluster_probe": (24.0, 2.0),
-    "run_batch_sharded": (48.0, 4.0),
-    "run_uniform_sharded": (32.0, 4.0),
-    "run_plan_sharded": (48.0, 4.0),
-    "run_gang_sharded": (64.0, 5.0),
-    "scatter_rows_sharded": (2.0, 3.0),
-    "cluster_probe_sharded": (24.0, 3.0),
 }
 
 BOUND_COMPUTE = "compute_bound"
@@ -104,12 +76,16 @@ def host_estimate(kernel: str, args: tuple) -> tuple:
     """(flops, bytes) from the dispatch args alone — the fallback when
     XLA reports nothing. Cells = total array elements across every array
     leaf of the args; bytes = their raw bytes times the kernel's revisit
-    multiplier."""
-    coeff = KERNEL_COSTS.get(kernel)
-    if coeff is None:
+    multiplier. The coefficients encode each kernel's work shape — the
+    scoring/filter kernels do a few tens of ops per node-pod cell, the
+    scan/wave kernels revisit the carry per segment, the probe/diagnose
+    reductions are single-pass. Rows costed this way carry
+    source="host"."""
+    entry = device_entries().get(kernel)
+    if entry is None:
         return (0.0, 0.0)
     import jax
-    flops_per_cell, byte_mult = coeff
+    flops_per_cell, byte_mult = entry.cost
     cells = 0
     nbytes = 0
     for leaf in jax.tree_util.tree_leaves(args):

@@ -8,14 +8,15 @@ placement system (Gavel, arXiv:2008.09213; topology-aware co-located LLM
 scheduling, arXiv:2411.11560) keeps an attributable cost profile of its
 own scheduler loop. This module is that profile's device half:
 
-- every public JIT entry (`ops/program.py` run_batch / run_uniform /
-  run_wave / run_wave_scan / wave_statics / diagnose_row /
-  dry_run_select_victims, `parallel/sharding.py` run_batch_sharded) calls
-  through `measured_call`, which detects fresh compiles via the jitted
-  function's `_cache_size()` delta and records per-kernel compile wall
-  seconds, call counts, retraces (compiles beyond the first) and
-  donated-buffer misses (a donated carry whose buffer survived the call —
-  the donation was ignored, so the dispatch paid a full carry copy);
+- every device entry (a public function that dispatches a jitted program)
+  is declared once, where it is defined, with `@device_entry`. The
+  declaration registers the entry's kernel label, donated argument and
+  host cost estimate, and runs every dispatch through `measured_call`,
+  which detects fresh compiles via the jitted function's `_cache_size()`
+  delta and records per-kernel compile wall seconds, call counts,
+  retraces (compiles beyond the first) and donated-buffer misses (a
+  donated carry whose buffer survived the call — the donation was
+  ignored, so the dispatch paid a full carry copy);
 - host↔device transfer sites (`state/tensorize.py` node-array uploads,
   `ops/groups.py` group-tensor uploads, the signature-table upload and
   the drain readbacks) report byte counts via `note_h2d`, keyed by the
@@ -38,10 +39,12 @@ ZERO compile delta — that invariant is the "no hidden retraces" test.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time as _time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
+import jax
 from jax.profiler import TraceAnnotation
 
 
@@ -89,15 +92,6 @@ class KernelRecord:
                 "runSeconds": round(self.run_seconds, 6),
                 "donationMisses": self.donation_misses}
 
-
-# every instrumented kernel, pre-seeded into the metric families so
-# dashboards see the series before the first dispatch
-KERNELS = ("run_batch", "run_uniform", "run_wave", "run_wave_scan",
-           "run_plan", "wave_statics", "diagnose", "dry_run",
-           "run_batch_sharded", "run_uniform_sharded", "run_plan_sharded",
-           "run_gang_sharded", "scatter_rows_sharded",
-           "cluster_probe_sharded", "run_gang",
-           "scatter_rows", "explain_row", "cluster_probe")
 
 # h2d phase labels, aligned with scheduler_drain_phase_seconds{phase}
 # where the transfer is paid (device_readback is the d2h direction of the
@@ -181,18 +175,6 @@ class CompileLedger:
             obs.on_compile(kernel, fn, args, kw)
         return out
 
-    def wrap(self, kernel: str, fn):
-        """Instrument a module-level jitted callable in place (the
-        non-factory entry points); keeps the wrapped signature."""
-        import functools
-
-        @functools.wraps(fn)
-        def wrapped(*args, **kw):
-            return self.measured_call(kernel, fn, *args, **kw)
-
-        wrapped.__wrapped__ = fn
-        return wrapped
-
     # -- transfer capture -----------------------------------------------------
 
     def note_h2d(self, phase: str, nbytes: int) -> None:
@@ -241,8 +223,73 @@ class CompileLedger:
 
 GLOBAL = CompileLedger()
 
-# resolved lazily (observatory imports KERNELS from this module, so a
-# top-level import back would be circular); cached after the first call
+
+@dataclass(frozen=True)
+class DeviceEntry:
+    """One declared device entry (see `device_entry`)."""
+
+    label: str          # the `kernel` label of its metric series
+    fn: object          # the public function
+    donates: int | None  # position of the argument it donates, if any
+    # host cost estimate (perf/costmodel.py host_estimate): flops per
+    # array cell of the args, and a multiplier over the args' raw bytes
+    cost: tuple
+
+
+# label → entry, filled as the declaring modules are imported
+_ENTRIES: dict[str, DeviceEntry] = {}
+
+
+def device_entry(label: str | None = None, *, cost: tuple,
+                 donates: int | None = None):
+    """Declare the decorated function a device entry.
+
+    The function prepares one dispatch and returns `(program, args)`: the
+    jitted program and its positional arguments. The declaration does
+    the rest: it stages `args` through the sanitizer rails, dispatches
+    through `GLOBAL.measured_call` under `label` (default: the function's
+    name), and handles donation. An entry that donates `args[donates]`
+    returns a donating program as its factory `donate -> jitted program`,
+    because only accelerator backends donate; on the CPU the declaration
+    poisons the carry instead. A program returned as itself never
+    donates.
+
+    jaxsan (analysis/jaxsan.py) reads these declarations from the source:
+    they are its entry points and its donating entries."""
+    def declare(prepare):
+        from ..analysis.rails import GLOBAL as rails
+        name = label or prepare.__name__
+
+        @functools.wraps(prepare)
+        def dispatch(*a, **kw):
+            program, args = prepare(*a, **kw)
+            args = rails.stage(args)
+            if hasattr(program, "lower"):   # jitted; a factory has no lower
+                return GLOBAL.measured_call(name, program, *args)
+            donate = jax.default_backend() != "cpu"
+            donated = args[donates]
+            out = GLOBAL.measured_call(name, program(donate), *args,
+                                       donated=donated if donate else None)
+            if not donate:
+                rails.poison_donated(donated, out)
+            return out
+
+        _ENTRIES[name] = DeviceEntry(name, dispatch, donates, tuple(cost))
+        return dispatch
+    return declare
+
+
+def device_entries() -> dict[str, DeviceEntry]:
+    """Every declared device entry, by label. Imports the declaring
+    modules first, so the set does not depend on what the caller has
+    imported."""
+    from ..ops import gang, program  # noqa: F401
+    from ..parallel import sharding  # noqa: F401
+    return _ENTRIES
+
+
+# resolved lazily (observatory imports this module, so a top-level
+# import back would be circular); cached after the first call
 _OBS = None
 
 

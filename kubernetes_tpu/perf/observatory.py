@@ -40,7 +40,7 @@ from __future__ import annotations
 import threading
 
 from .costmodel import CostModel, classify, modeled_seconds
-from .ledger import KERNELS
+from .ledger import device_entries
 
 # log2-spaced bucket edges, 1µs .. ~67s: edge[i] = 1e-6 * 2**i. A
 # dispatch landing beyond the last edge folds into the final bucket —
@@ -51,32 +51,6 @@ _EDGES = tuple(1e-6 * (2.0 ** i) for i in range(27))
 # into "~other" so shape churn can't grow the observatory unboundedly
 MAX_PLAN_KEYS = 32
 _OVERFLOW_KEY = "~other"
-
-# jaxsan ENTRY_POINT function name → ledger/observatory kernel name.
-# tools/check.py walks this: a JIT entry missing here (or mapping to an
-# unknown kernel) fails the config check — a new kernel cannot land
-# unmeasured. The names differ where the public wrapper is not the
-# kernel ("diagnose_row" dispatches the "diagnose" reductions).
-ENTRY_KERNELS = {
-    "run_batch": "run_batch",
-    "run_uniform": "run_uniform",
-    "run_wave": "run_wave",
-    "run_wave_scan": "run_wave_scan",
-    "run_plan": "run_plan",
-    "wave_statics": "wave_statics",
-    "diagnose_row": "diagnose",
-    "dry_run_select_victims": "dry_run",
-    "scatter_rows": "scatter_rows",
-    "explain_row": "explain_row",
-    "cluster_probe": "cluster_probe",
-    "run_gang": "run_gang",
-    "run_batch_sharded": "run_batch_sharded",
-    "run_uniform_sharded": "run_uniform_sharded",
-    "run_plan_sharded": "run_plan_sharded",
-    "run_gang_sharded": "run_gang_sharded",
-    "scatter_rows_sharded": "scatter_rows_sharded",
-    "cluster_probe_sharded": "cluster_probe_sharded",
-}
 
 
 def _quantile(counts, total: int, q: float) -> float:
@@ -196,10 +170,10 @@ class KernelObservatory:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._enabled = True
-        # pre-seed every instrumented kernel so /debug/kernels (and the
-        # metric mirror) list all thirteen before the first dispatch
+        # pre-seed every device entry's kernel so /debug/kernels (and
+        # the metric mirror) list them all before the first dispatch
         self.kernels: dict[str, _KernelStats] = {
-            k: _KernelStats() for k in KERNELS}
+            k: _KernelStats() for k in device_entries()}
         self._device_kind = ""
         self._shard_profile: dict = {}
         self._tl = threading.local()
@@ -439,7 +413,7 @@ class KernelObservatory:
     def reset(self) -> None:
         """Test hook, mirroring `CompileLedger.reset`."""
         with self._lock:
-            self.kernels = {k: _KernelStats() for k in KERNELS}
+            self.kernels = {k: _KernelStats() for k in device_entries()}
             self._shard_profile = {}
         self.costs.reset()
 

@@ -227,8 +227,9 @@ class TestClusterProbeRails:
 
 class TestProbeRegistration:
     def test_all_kernels_ledgered_and_sanitized(self):
-        from kubernetes_tpu.analysis.jaxsan import ENTRY_POINTS
-        from kubernetes_tpu.perf.ledger import KERNELS
-        assert "cluster_probe" in KERNELS and len(KERNELS) == 18
-        assert "cluster_probe_sharded" in KERNELS
-        assert "cluster_probe" in ENTRY_POINTS["kubernetes_tpu.ops.program"]
+        from kubernetes_tpu.perf.ledger import device_entries
+        kernels = device_entries()
+        assert "cluster_probe" in kernels and len(kernels) == 18
+        assert "cluster_probe_sharded" in kernels
+        # the declaration is the dispatch path: staged and measured
+        assert kernels["cluster_probe"].fn is cluster_probe

@@ -416,7 +416,7 @@ class TestExpositionLint:
         of lanes on the shard gauge — so the generic lint exercises
         them before the first dispatch."""
         from kubernetes_tpu.metrics import SHARD_SEED_LANES
-        from kubernetes_tpu.perf.ledger import KERNELS
+        from kubernetes_tpu.perf.ledger import device_entries
         m = SchedulerMetrics()
         series, helps, types = _parse_exposition(m.exposition())
         assert types["scheduler_kernel_device_seconds"] == "counter"
@@ -426,7 +426,7 @@ class TestExpositionLint:
         for fam in ("scheduler_kernel_device_seconds",
                     "scheduler_kernel_dispatch_total"):
             kernels = {lbl["kernel"] for lbl, _v in series[fam]}
-            assert kernels == set(KERNELS), fam
+            assert kernels == set(device_entries()), fam
         lanes = {lbl["lane"] for lbl, _v in
                  series["scheduler_shard_lane_seconds"]}
         assert lanes == set(SHARD_SEED_LANES)

@@ -22,8 +22,6 @@ Covers the full stack the tentpole ships:
   families move, and the gate off means no stamp, no movement, 404;
 - /debug/criticalpath over a live SchedulerServer (last-N window +
   aggregate, ?limit=N, 404 with the gate off);
-- tools/check.py `cost_model_gaps` (the exit-2 config rule mirroring
-  observatory_gaps);
 - stall attribution under the streaming pipeline (ISSUE 20 satellite):
   backpressure in EACH direction yields a `backpressure` verdict whose
   stall seconds are conserved against the pipeline's own stall clock
@@ -50,7 +48,6 @@ sys.path.insert(0, REPO)
 from kubernetes_tpu.backend.apiserver import APIServer  # noqa: E402
 from kubernetes_tpu.config import KubeSchedulerConfiguration  # noqa: E402
 from kubernetes_tpu.perf import costmodel  # noqa: E402
-from kubernetes_tpu.perf import observatory as obs_mod  # noqa: E402
 from kubernetes_tpu.perf.costmodel import (CostModel,  # noqa: E402
                                            classify, host_estimate,
                                            modeled_seconds)
@@ -58,6 +55,7 @@ from kubernetes_tpu.perf.critical_path import (CAUSES,  # noqa: E402
                                                aggregate, attribute_delta,
                                                attribute_drain,
                                                ceiling_factor, phase_shares)
+from kubernetes_tpu.perf.ledger import device_entries  # noqa: E402
 from kubernetes_tpu.perf.observatory import GLOBAL as OBS  # noqa: E402
 from kubernetes_tpu.pipeline import STAGES, StreamingPipeline  # noqa: E402
 from kubernetes_tpu.scheduler import Scheduler  # noqa: E402
@@ -349,7 +347,7 @@ class TestCostModelUnits:
         import numpy as np
         a = np.ones((10, 8), np.float32)
         flops, nbytes = host_estimate("run_batch", (a,))
-        fpc, bmult = costmodel.KERNEL_COSTS["run_batch"]
+        fpc, bmult = device_entries()["run_batch"].cost
         assert flops == pytest.approx(80 * fpc)
         assert nbytes == pytest.approx(a.nbytes * bmult)
         assert host_estimate("no_such_kernel", (a,)) == (0.0, 0.0)
@@ -366,7 +364,7 @@ class TestCostModelUnits:
         mesh = make_mesh(min(2, len(jax.devices())))
         flops, nbytes = host_estimate(
             "scatter_rows_sharded", (mesh, (a, (b,)), 3, "cfg"))
-        fpc, bmult = costmodel.KERNEL_COSTS["scatter_rows_sharded"]
+        fpc, bmult = device_entries()["scatter_rows_sharded"].cost
         assert flops == pytest.approx(84 * fpc)
         assert nbytes == pytest.approx((a.nbytes + b.nbytes) * bmult)
 
@@ -578,37 +576,6 @@ class TestDebugEndpoint:
                 srv.stop()
         finally:
             OBS.enable_cost_model(True)
-
-
-# ---------------------------------------------------------------------------
-# tools/check.py cost_model_gaps
-
-
-def _load_check():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "_tpu_tools_check", os.path.join(REPO, "tools", "check.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestCostModelGaps:
-    def test_real_config_fully_covered(self):
-        assert _load_check().cost_model_gaps() == []
-
-    def test_kernel_without_cost_entry_reported(self, monkeypatch):
-        monkeypatch.setitem(obs_mod.ENTRY_KERNELS, "weird_fn",
-                            "no_such_kernel")
-        gaps = _load_check().cost_model_gaps({"m": ("weird_fn",)})
-        assert len(gaps) == 1
-        assert "m.weird_fn" in gaps[0] and "no_such_kernel" in gaps[0]
-        assert "KERNEL_COSTS" in gaps[0]
-
-    def test_unmapped_entry_left_to_observatory_gaps(self):
-        # no ENTRY_KERNELS mapping at all: observatory_gaps owns that
-        # finding; cost_model_gaps must not double-report it
-        assert _load_check().cost_model_gaps({"m": ("bogus_fn",)}) == []
 
 
 # ---------------------------------------------------------------------------

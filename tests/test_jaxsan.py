@@ -65,9 +65,12 @@ def helper(x, sel, k):
 _HOST_BAD = '''
 import numpy as np
 
+from kubernetes_tpu.perf.ledger import device_entry
+
 from .device import enter
 
 
+@device_entry(donates=2, cost=(1.0, 1.0))
 def run_batch(cfg, na, carry, pods):
     return carry
 
@@ -137,9 +140,12 @@ def helper(x, sel, k):
 _HOST_CLEAN = '''
 import numpy as np
 
+from kubernetes_tpu.perf.ledger import device_entry
+
 from .device import enter
 
 
+@device_entry(donates=2, cost=(1.0, 1.0))
 def run_batch(cfg, na, carry, pods):
     return carry
 
@@ -361,16 +367,10 @@ class TestRepoGate:
         an = JaxsanAnalyzer(REPO).load()
         an.run()
         assert an.check_entry_coverage() == []
-        # the declared entry set is exactly the ledger's kernel surface
+        # the entry set jaxsan lints is exactly the declared registry
+        from kubernetes_tpu.perf.ledger import device_entries
         names = {n for mod, ns in an.entry_points.items() for n in ns}
-        assert names == {"run_batch", "run_uniform", "run_wave",
-                         "run_wave_scan", "run_plan", "wave_statics",
-                         "diagnose_row", "dry_run_select_victims",
-                         "run_batch_sharded", "run_uniform_sharded",
-                         "run_plan_sharded", "run_gang_sharded",
-                         "scatter_rows_sharded", "cluster_probe_sharded",
-                         "run_gang", "scatter_rows",
-                         "explain_row", "cluster_probe"}
+        assert names == {e.fn.__name__ for e in device_entries().values()}
 
     def test_threaded_subsystems_are_annotated(self):
         """The lock checker's input contract: the shared rings/queues of

@@ -14,7 +14,7 @@ Covers the full stack the tentpole ships:
   nested inside their drain's device_dispatch span;
 - sharded-lane profiling on the 8-device test mesh;
 - retrace_budget(0) holding over warm re-runs with the observatory ON;
-- tools/kernel_sweep.py --self-test and tools/check.py observatory_gaps;
+- tools/kernel_sweep.py --self-test, and each entry's kernel label;
 - the slow-marked throughput gate: observatory ON within 5% of OFF.
 """
 
@@ -39,17 +39,17 @@ from kubernetes_tpu.backend.apiserver import APIServer  # noqa: E402
 from kubernetes_tpu.config import KubeSchedulerConfiguration  # noqa: E402
 from kubernetes_tpu.parallel.sharding import make_mesh  # noqa: E402
 from kubernetes_tpu.perf.ledger import (GLOBAL as LEDGER,  # noqa: E402
-                                        KERNELS, CompileLedger,
-                                        KernelRecord)
-from kubernetes_tpu.perf import observatory as obs_mod  # noqa: E402
+                                        CompileLedger, KernelRecord,
+                                        device_entries)
 from kubernetes_tpu.perf.observatory import (GLOBAL as OBS,  # noqa: E402
                                              _KernelStats, _OVERFLOW_KEY,
-                                             ENTRY_KERNELS, MAX_PLAN_KEYS,
-                                             StreamingHist)
+                                             MAX_PLAN_KEYS, StreamingHist)
 from kubernetes_tpu.scheduler import Scheduler  # noqa: E402
 from kubernetes_tpu.server import SchedulerServer  # noqa: E402
 from kubernetes_tpu.testing.wrappers import make_node, make_pod  # noqa: E402
 from kubernetes_tpu.utils.tracing import Tracer  # noqa: E402
+
+KERNELS = tuple(device_entries())
 
 
 @pytest.fixture
@@ -304,9 +304,12 @@ class TestObservatoryCapture:
         assert shard == {}
 
     def test_entry_kernels_cover_ledger(self):
-        # every mapped kernel is a real ledger kernel, and the map spans
-        # all thirteen (the tools/check.py config gate's ground truth)
-        assert set(ENTRY_KERNELS.values()) == set(KERNELS)
+        # an entry's kernel label is its function's name, except for the
+        # two public wrappers named after what they do
+        renamed = {e.fn.__name__: k for k, e in device_entries().items()
+                   if e.fn.__name__ != k}
+        assert renamed == {"diagnose_row": "diagnose",
+                           "dry_run_select_victims": "dry_run"}
 
 
 # ---------------------------------------------------------------------------
@@ -489,30 +492,6 @@ class TestKernelSweep:
             capture_output=True, text=True, env=env, timeout=300)
         assert p.returncode == 0, p.stdout + p.stderr
         assert "self-test: OK" in p.stdout
-
-
-def _load_check():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "_tpu_tools_check", os.path.join(REPO, "tools", "check.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestObservatoryGaps:
-    def test_real_config_fully_covered(self):
-        assert _load_check().observatory_gaps() == []
-
-    def test_unmapped_entry_reported(self):
-        gaps = _load_check().observatory_gaps({"m": ("bogus_fn",)})
-        assert gaps == ["m.bogus_fn (not in ENTRY_KERNELS)"]
-
-    def test_entry_mapped_to_unknown_kernel(self, monkeypatch):
-        monkeypatch.setitem(obs_mod.ENTRY_KERNELS, "weird_fn",
-                            "no_such_kernel")
-        gaps = _load_check().observatory_gaps({"m": ("weird_fn",)})
-        assert gaps and "no_such_kernel" in gaps[0]
 
 
 # ---------------------------------------------------------------------------

@@ -27,7 +27,7 @@ import jax  # noqa: E402
 from kubernetes_tpu.backend.apiserver import APIServer  # noqa: E402
 from kubernetes_tpu.metrics import (LOOP_PHASES, SIDE_THREADS,  # noqa: E402
                                     Counter, SchedulerMetrics)
-from kubernetes_tpu.perf.ledger import KERNELS  # noqa: E402
+from kubernetes_tpu.perf.ledger import device_entries  # noqa: E402
 from kubernetes_tpu.perf.profiler import HostProfiler  # noqa: E402
 from kubernetes_tpu.scheduler import Scheduler  # noqa: E402
 from kubernetes_tpu.testing.wrappers import make_node, make_pod  # noqa: E402
@@ -161,7 +161,7 @@ class TestProfilerTrace:
                    for e in by["pod_update"])
         dispatches = [e for e in events if e[0].startswith("dispatch:")]
         assert dispatches
-        assert {e[0].split(":", 1)[1] for e in dispatches} <= set(KERNELS)
+        assert {e[0].split(":", 1)[1] for e in dispatches} <= set(device_entries())
         assert any(_inside(d, dev) for d in dispatches
                    for dev in by["device_dispatch"])
 

@@ -75,7 +75,7 @@ class TestTransferGuardDrain:
     def test_group_wave_drain_under_ambient_disallow(self, rails_off_after):
         """The wave path too: spread pods exercise wave_statics (whose
         lazy cache fill runs INSIDE the dispatch region — it opens the
-        declared host_cache window) and the donating run_wave_scan."""
+        declared host_cache window) and the donating run_plan."""
         api, sched = _cluster(nodes=8)
 
         def spread(name):
@@ -247,7 +247,8 @@ class TestRetraceBudgetRegression:
         sys.path.insert(0, repo)
         import bench
         from kubernetes_tpu.perf.harness import run_config
-        from kubernetes_tpu.perf.ledger import KERNELS
+        from kubernetes_tpu.perf.ledger import device_entries
+        kernels = tuple(device_entries())
 
         cfg = os.path.join(repo, "kubernetes_tpu", "perf", "configs",
                            "performance-config.yaml")
@@ -258,7 +259,7 @@ class TestRetraceBudgetRegression:
                 run_config(cfg, case, small_wl)
                 deltas = {k: r.compiles - before.get(k, 0)
                           for k, r in LEDGER.kernels.items()
-                          if k in KERNELS and r.compiles - before.get(k, 0)}
+                          if k in kernels and r.compiles - before.get(k, 0)}
                 if not deltas:
                     break
             else:
@@ -267,7 +268,7 @@ class TestRetraceBudgetRegression:
             # the fixed point must HOLD: the next full re-run fits a zero
             # retrace budget across all thirteen entry kernels (raises
             # RetraceBudgetExceeded otherwise)
-            with RAILS.retrace_budget(0, kernels=KERNELS):
+            with RAILS.retrace_budget(0, kernels=kernels):
                 run_config(cfg, case, small_wl)
         assert not never_stable, (
             f"kernels still minting after {self.WARM_PASSES_MAX} warm "

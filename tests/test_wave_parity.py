@@ -1,6 +1,6 @@
 """Speculative wave placement ↔ serial-order parity (ISSUE 3 standing gate).
 
-The wave kernels (ops/program.py run_wave / run_wave_scan) must produce
+The wave kernels (ops/program.py run_wave / run_plan) must produce
 assignments BIT-IDENTICAL to the sequential greedy in every scenario —
 the merge tier's conflict detection, the exact minimum-level replay, the
 domain-veto champion selection, and the in-dispatch serial repair are all
@@ -31,8 +31,7 @@ from kubernetes_tpu.ops.groups import to_device
 from kubernetes_tpu.ops.hostgreedy import static_norm_ok
 from kubernetes_tpu.ops.program import (ScoreConfig, WaveXs, initial_carry,
                                         pod_rows_from_batch, run_batch,
-                                        run_wave, run_wave_scan,
-                                        wave_statics)
+                                        run_plan, run_wave, wave_statics)
 from kubernetes_tpu.state.batch import BatchBuilder
 from kubernetes_tpu.state.tensorize import ClusterState, pow2_at_least
 from kubernetes_tpu.testing.wrappers import make_node, make_pod
@@ -129,13 +128,13 @@ def wave_vs_scan(nodes, existing, pods, cfg=ScoreConfig(), merge_on=True):
     st_list = _statics_for(na, table, wt_list)
     statics = tuple(jnp.stack([s[f] for s in st_list]) for f in range(4))
     wxs = WaveXs(valid=jnp.asarray(valid), widx=jnp.asarray(widx))
-    _, packed2 = run_wave_scan(
+    _, packed2 = run_plan(
         cfg, na, initial_carry(na, gc), wxs, table,
         jnp.asarray(np.array(wt_list, np.int32)), gd, statics, fam,
         norm_live)
     ws_out = np.asarray(packed2)[:n]
     assert (ws_out == scan_out).all(), (
-        "run_wave_scan diverged", scan_out.tolist(), ws_out.tolist())
+        "run_plan diverged", scan_out.tolist(), ws_out.tolist())
     return scan_out, stats
 
 
@@ -212,7 +211,7 @@ class TestWaveFamilies:
 
     def test_affinity_routes_to_wavescan(self):
         # self-matching required affinity: the same-signature kernel's
-        # merge/serial state can't carry it; run_wave_scan must be exact
+        # merge/serial state can't carry it; run_plan must be exact
         ex = [(make_pod("seed").req({"cpu": "1", "memory": "1Gi"})
                .label("app", "aff").obj(), "n0")]
         pods = [make_pod(f"q{i}").req({"cpu": "1", "memory": "1Gi"})
@@ -239,7 +238,7 @@ class TestWaveFamilies:
         statics = tuple(jnp.stack([s[f] for s in st_list]) for f in range(4))
         wxs = WaveXs(valid=jnp.asarray(valid),
                      widx=jnp.asarray(np.zeros((B,), np.int32)))
-        _, packed = run_wave_scan(
+        _, packed = run_plan(
             ScoreConfig(), na, initial_carry(na, gc), wxs, table,
             jnp.asarray(np.array([u, u], np.int32)), gd, statics, fam,
             False)

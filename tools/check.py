@@ -6,18 +6,19 @@
     python tools/check.py --list-waivers  # audit the waiver baseline
     python tools/check.py --json          # machine-readable findings
 
+The entry points are the package's device entries: the functions
+declared with `@device_entry` (perf/ledger.py), which also gives each
+its kernel label, donated argument and cost coefficients, so no list
+of entries is kept here.
+
 Exit codes: 0 = no unwaived findings; 1 = findings; 2 = configuration
-error (a declared JIT entry point no longer reaches a jitted function —
-the lint silently lost device-path coverage — or is missing from the
-kernel observatory's ENTRY_KERNELS map, so its dispatches would go
-unmeasured, or its kernel has no device cost-model entry
-(perf/costmodel.py KERNEL_COSTS), so its observatory rows would carry
-no compute/memory/comms-bound classification — `cost_model_gaps` — or
-the streaming pipeline grew a dispatch path that
-bypasses the measured_call/observatory seams — `pipeline_stages` — or a
-telemetry surface lost coverage: a registered metric family without a
-pre-seeded sample / bench-archive TYPE line, or a journey event/cause
-the /debug/pod renderer cannot annotate — `obs_coverage`).
+error (a declared device entry no longer reaches a jitted function —
+the lint silently lost device-path coverage — or the streaming pipeline
+grew a dispatch path that bypasses the device entries —
+`pipeline_stages` — or a telemetry surface lost coverage: a registered
+metric family without a pre-seeded sample / bench-archive TYPE line, or
+a journey event/cause the /debug/pod renderer cannot annotate —
+`obs_coverage`).
 
 The same analysis runs in tier-1 via tests/test_jaxsan.py, so CI fails
 on any unwaived finding; this CLI is the local/fix-up loop. Waiver
@@ -56,51 +57,6 @@ def run_check(root: str = _REPO, package: str = "kubernetes_tpu",
     return findings, an
 
 
-def observatory_gaps(entry_points=None) -> list:
-    """Entries the kernel observatory cannot attribute (ISSUE 14): every
-    jaxsan ENTRY_POINT function must map to a ledger kernel via
-    perf/observatory.py ENTRY_KERNELS — a new JIT entry cannot land
-    unmeasured. Returns ["mod.fn (reason)", ...]; empty = covered."""
-    from kubernetes_tpu.analysis.jaxsan import ENTRY_POINTS
-    from kubernetes_tpu.perf.ledger import KERNELS
-    from kubernetes_tpu.perf.observatory import ENTRY_KERNELS
-
-    gaps: list[str] = []
-    for mod, names in (entry_points or ENTRY_POINTS).items():
-        for name in names:
-            kernel = ENTRY_KERNELS.get(name)
-            if kernel is None:
-                gaps.append(f"{mod}.{name} (not in ENTRY_KERNELS)")
-            elif kernel not in KERNELS:
-                gaps.append(f"{mod}.{name} (maps to unknown kernel "
-                            f"{kernel!r})")
-    return gaps
-
-
-def cost_model_gaps(entry_points=None) -> list:
-    """ISSUE 20 `cost_model_gaps` check: every jaxsan ENTRY_POINT must
-    resolve to a kernel with a host-estimator cost entry
-    (perf/costmodel.py KERNEL_COSTS) — a new JIT entry cannot land
-    without a flops/bytes model, or its observatory rows would carry no
-    bound classification when XLA's cost_analysis is unavailable.
-    Mirrors `observatory_gaps`. Returns ["mod.fn (reason)", ...];
-    empty = covered."""
-    from kubernetes_tpu.analysis.jaxsan import ENTRY_POINTS
-    from kubernetes_tpu.perf.costmodel import KERNEL_COSTS
-    from kubernetes_tpu.perf.observatory import ENTRY_KERNELS
-
-    gaps: list[str] = []
-    for mod, names in (entry_points or ENTRY_POINTS).items():
-        for name in names:
-            kernel = ENTRY_KERNELS.get(name)
-            if kernel is None:
-                continue     # observatory_gaps already reports this
-            if kernel not in KERNEL_COSTS:
-                gaps.append(f"{mod}.{name} (kernel {kernel!r} has no "
-                            "perf/costmodel.py KERNEL_COSTS entry)")
-    return gaps
-
-
 # The streaming pipeline's only sanctioned routes to the device: the
 # Scheduler seams, which run every kernel through
 # CompileLedger.measured_call under the observatory capture installed by
@@ -122,8 +78,7 @@ def pipeline_stage_gaps(path: str = None, source: str = None) -> list:
     every dispatch path keeps measured_call/observatory attribution."""
     import ast
 
-    from kubernetes_tpu.analysis.jaxsan import ENTRY_POINTS
-    from kubernetes_tpu.perf.observatory import ENTRY_KERNELS
+    from kubernetes_tpu.perf.ledger import device_entries
 
     if source is None:
         path = path or os.path.join(_REPO, "kubernetes_tpu", "pipeline.py")
@@ -132,8 +87,7 @@ def pipeline_stage_gaps(path: str = None, source: str = None) -> list:
     fname = os.path.basename(path or "pipeline.py")
     tree = ast.parse(source, filename=fname)
 
-    entry_names = ({n for names in ENTRY_POINTS.values() for n in names}
-                   | set(ENTRY_KERNELS))
+    entry_names = {e.fn.__name__ for e in device_entries().values()}
     banned_abs = ("jax", "kubernetes_tpu.ops", "kubernetes_tpu.parallel")
     banned_rel = ("ops", "parallel")
 
@@ -255,7 +209,8 @@ def main(argv=None) -> int:
     ap.add_argument("--entries", action="append", default=None,
                     metavar="MOD:NAME,NAME",
                     help="override the JIT entry points (repeatable); "
-                         "default: the nine kubernetes_tpu entries")
+                         "default: the package's @device_entry "
+                         "declarations")
     args = ap.parse_args(argv)
 
     entry_points = None
@@ -268,11 +223,8 @@ def main(argv=None) -> int:
     findings, an = run_check(args.root, args.package, entry_points)
     live = [f for f in findings if not f.waived]
     waived = [f for f in findings if f.waived]
-    # the observatory-coverage gate guards the REPO's declared entry
-    # points; an ad-hoc --entries override lints someone else's tree,
-    # whose functions have no business in ENTRY_KERNELS
-    obs_gaps = [] if entry_points is not None else observatory_gaps()
-    cost_gaps = [] if entry_points is not None else cost_model_gaps()
+    # the coverage gates guard the REPO's own surfaces; an ad-hoc
+    # --entries override lints someone else's tree
     pipe_gaps = [] if entry_points is not None else pipeline_stage_gaps()
     cov_gaps = [] if entry_points is not None else obs_coverage()
 
@@ -281,8 +233,6 @@ def main(argv=None) -> int:
             "findings": [f.to_dict() for f in live],
             "waived": [f.to_dict() for f in waived],
             "missingEntries": an.missing_entries,
-            "observatoryGaps": obs_gaps,
-            "costModelGaps": cost_gaps,
             "pipelineStageGaps": pipe_gaps,
             "obsCoverageGaps": cov_gaps,
             "modules": len(an.modules),
@@ -303,16 +253,6 @@ def main(argv=None) -> int:
     if an.missing_entries:
         print("jaxsan: CONFIG ERROR — entries without jit coverage: "
               + ", ".join(an.missing_entries), file=sys.stderr)
-        return 2
-    if obs_gaps:
-        print("jaxsan: CONFIG ERROR — entries invisible to the kernel "
-              "observatory (perf/observatory.py ENTRY_KERNELS): "
-              + ", ".join(obs_gaps), file=sys.stderr)
-        return 2
-    if cost_gaps:
-        print("jaxsan: CONFIG ERROR — cost_model_gaps: entries without "
-              "a device cost-model entry (perf/costmodel.py "
-              "KERNEL_COSTS): " + ", ".join(cost_gaps), file=sys.stderr)
         return 2
     if pipe_gaps:
         print("jaxsan: CONFIG ERROR — pipeline_stages: a dispatch path "
