@@ -31,6 +31,10 @@ class _PodState:
     assumed: bool = False
     deadline: Optional[float] = None  # assumed-pod expiry; None = no expiry
     binding_finished: bool = False
+    # (the builder's row_facts list, signature row) the device placed the
+    # pod from; the list is replaced on a table reset, so `row[0] is
+    # builder.row_facts` says the row index is still current
+    row: Optional[tuple] = None
 
 
 class _NodeItem:
@@ -167,12 +171,14 @@ class Cache:
         uid = pod.uid
         ps = self.pod_states.get(uid)
         if ps is not None and ps.assumed:
+            row = ps.row
             if ps.pod.spec.node_name != pod.spec.node_name:
                 # assumed on one node, bound on another: relocate
                 self._remove_pod_from_node(ps.pod)
                 self._add_pod_to_node(pod)
+                row = None
             self.assumed_pods.discard(uid)
-            self.pod_states[uid] = _PodState(pod=pod)
+            self.pod_states[uid] = _PodState(pod=pod, row=row)
             return
         if ps is not None:
             return  # duplicate add: ignore (cache logs error)

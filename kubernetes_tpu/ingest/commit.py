@@ -111,7 +111,9 @@ class CommitEngine:
             pi = qpi.pod_info
             pi.pod = assumed
             qpi.pod = assumed   # keep the slot in sync with pod_info
+            row = None
             if tidx is not None and tidx[i] < n_facts:
+                row = (facts_list, tidx[i])
                 f = facts_list[tidx[i]]
             else:  # row minted outside the batch (defensive): derive
                 from .columns import commit_facts_for_row
@@ -137,7 +139,8 @@ class CommitEngine:
                 info._update_ports(assumed, add=True)
             info.generation = next_generation()
             move_to_head(item)
-            st = _PodState(pod=assumed, assumed=True, binding_finished=True)
+            st = _PodState(pod=assumed, assumed=True, binding_finished=True,
+                           row=row)
             if ttl > 0:
                 st.deadline = now + ttl
             pod_states[uid] = st

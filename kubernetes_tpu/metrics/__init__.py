@@ -291,6 +291,9 @@ SIDE_THREADS = ("audit", "host_profiler")
 AUDIT_REPLAY_ROUTES = ("process", "thread")
 # the handler paths that confirm a bind echo (bind_echo_pods_total)
 BIND_ECHO_PATHS = ("bulk", "per_pod")
+# how the resident device carry took a bound pod's delete
+# (pod_delete_carry_total)
+POD_DELETE_CARRY_PATHS = ("delta", "reseed")
 # the placement tiers that resolve a drain's pods (placement_pods_total)
 PLACEMENT_TIERS = ("uniform", "wave_merge", "wave_serial", "wavescan", "scan",
                    "gang", "host_greedy", "host")
@@ -484,6 +487,14 @@ class SchedulerMetrics:
             "handler: foreign binds, and batches arriving while pods are "
             "unschedulable, bind errors are pending or pods wait at "
             "Permit).",
+            ("path",)))
+        self.pod_delete_carry = r.register(Counter(
+            n + "pod_delete_carry_total",
+            "Deletes of bound pods, by how the resident device carry took "
+            "them: delta (folded out of the carry as a negative placement "
+            "before the next drain) or reseed (the carry was dropped, or "
+            "was not resident, and the next drain reseeds it from the "
+            "host snapshot).",
             ("path",)))
         self.placement_pods = r.register(Counter(
             n + "placement_pods_total",
@@ -767,6 +778,8 @@ class SchedulerMetrics:
             self.thread_cpu_seconds.inc(thread, by=0)
         for path in BIND_ECHO_PATHS:
             self.bind_echo_pods.inc(path, by=0)
+        for path in POD_DELETE_CARRY_PATHS:
+            self.pod_delete_carry.inc(path, by=0)
         for tier in PLACEMENT_TIERS:
             self.placement_pods.inc(tier, by=0)
         # remaining registered-but-unseeded series: dashboards and

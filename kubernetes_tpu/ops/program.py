@@ -38,7 +38,7 @@ from jax import lax
 
 from ..ops.collectives import pmax
 from ..ops.groups import (DryRunSpread, GroupCarry, GroupFamilies, GroupsDev,
-                          group_mask, group_scores, group_update)
+                          group_mask, group_scores, group_update, wave_fold)
 from ..state.batch import (OP_DOES_NOT_EXIST, OP_EXISTS, OP_GT, OP_IN,
                            OP_LT, OP_NOT_IN, TOL_EQUAL, TOL_EXISTS)
 from ..state.tensorize import (EFFECT_NO_EXECUTE, EFFECT_NO_SCHEDULE,
@@ -2095,6 +2095,59 @@ def dry_run_select_victims(na: NodeArrays, pod: PodRow, cand,
     return _dry_run_select_victims_jit, (na, pod, cand, victim_req,
                                          victim_valid, ovl_used, ovl_npods,
                                          spread)
+
+
+class Retire(NamedTuple):
+    """Bound pods leaving the resident carry, grouped by signature row
+    ([S] = distinct rows, padded by repeating the first with zero
+    counts)."""
+
+    wt: object           # i32 [S] — table rows
+    cnt: object          # i32 [S, N] — pods of row s deleted from node n
+    req: object          # i64 [S, R] — the rows' request vectors
+    nonzero_req: object  # i64 [S, 2]
+    port_ids: object     # i32 [S, P] — interned host-port ids (0 = none)
+
+
+@functools.partial(jax.jit, static_argnames=("fam",))
+def _retire_pods_jit(carry: Carry, rt: Retire, gd: GroupsDev | None,
+                     fam: GroupFamilies | None):
+    cnt64 = rt.cnt.astype(jnp.int64)
+    # a TPU has no 64-bit integer dot: multiply and sum over the rows
+    used = carry.used - jnp.sum(cnt64[:, :, None] * rt.req[:, None, :],
+                                axis=0)
+    nonzero = carry.nonzero_used - jnp.sum(
+        cnt64[:, :, None] * rt.nonzero_req[:, None, :], axis=0)
+    npods = carry.npods - jnp.sum(rt.cnt, axis=0).astype(carry.npods.dtype)
+    # a host port id sits in at most one slot of a node's row (ports_mask
+    # refuses a collision), so clearing every slot a retired row's ids
+    # hold frees exactly the retired pods' ports
+    held = jnp.any((rt.cnt[:, :, None, None] > 0)
+                   & (carry.ports[None, :, :, None]
+                      == rt.port_ids[:, None, None, :])
+                   & (rt.port_ids[:, None, None, :] != 0), axis=(0, 3))
+    groups = carry.groups
+    if groups is not None:
+        groups = wave_fold(gd, groups, rt.wt, -rt.cnt, fam)
+    return carry._replace(
+        used=used, nonzero_used=nonzero, npods=npods,
+        ports=jnp.where(held, 0, carry.ports),
+        cache=carry.cache._replace(sig=jnp.int32(0)), groups=groups)
+
+
+@device_entry(cost=(8.0, 2.0))
+def retire_pods(carry: Carry, rt: Retire, gd: GroupsDev | None = None,
+                fam: GroupFamilies | None = None):
+    """Fold deleted bound pods out of the resident carry: the negative of
+    their placements. The node usage loses each row's requests, pod count
+    and host ports at its nodes; the group counts take `wave_fold` with
+    the counts negated (every count is a gated sum over placed pods,
+    linear in the placement counts, on static node labels), so the carry
+    equals a reseed from the snapshot without the pods. The signature
+    cache is invalidated: freed capacity moves its fit and scores.
+    Non-donating: the input carry may still be read by an in-flight
+    drain's probe."""
+    return _retire_pods_jit, (carry, rt, gd, fam)
 
 
 def initial_carry(na: NodeArrays, groups: GroupCarry | None = None) -> Carry:

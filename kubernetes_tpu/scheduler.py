@@ -47,9 +47,9 @@ from .obs.journey import (EV_ADOPT as _EV_ADOPT, EV_ASSIGN as _EV_ASSIGN,
                           EV_DRAIN as _EV_DRAIN, EV_EVICT as _EV_EVICT,
                           EV_FIT_ERROR as _EV_FIT_ERROR,
                           EV_PARK as _EV_PARK, EV_REQUEUE as _EV_REQUEUE)
-from .ops.program import (PROBE_STATS, PodXs, ScoreConfig, WaveXs,
-                          cluster_probe, initial_carry, run_batch,
-                          run_plan, run_uniform, run_wave,
+from .ops.program import (PROBE_STATS, PodXs, Retire, ScoreConfig, WaveXs,
+                          cluster_probe, initial_carry, retire_pods,
+                          run_batch, run_plan, run_uniform, run_wave,
                           table_from_batch)
 from .plugins import noderesources as nr
 from .plugins.node_basics import (NodeName, NodePorts, NodeUnschedulable,
@@ -261,6 +261,10 @@ class _PendingDrain:
     # device_dispatch span (perf/observatory.py device lane); {} with the
     # KernelObservatory gate off
     kernels: dict = field(default_factory=dict)
+    # the retire folds applied to the carry between the previous drain's
+    # dispatch and this one's (Scheduler._fold_retires): a replay of this
+    # drain applies them again first
+    retire: tuple = ()
 
     def ready(self) -> bool:
         return all(r.result.is_ready() for r in self.records
@@ -731,6 +735,13 @@ class Scheduler:
         #                              for; any pow2 crossing of the live
         #                              row count (or node growth) reseeds
         self._seeded_rows = 0        # signature rows whose counts are seeded
+        # bound pods deleted while the carry is resident, as (signature
+        # row, node row): folded out of the carry before the next dispatch
+        # instead of reseeding it (_fold_retires)
+        self._retires: list[tuple[int, int]] = []
+        # the folds applied since the last drain was dispatched, for a
+        # replay of the drains in flight (_replay_downstream)
+        self._retire_folds: list = []
         # the drain compiler (kubernetes_tpu/compiler/): maps every
         # drain's pod mix to a static device program over the pow2
         # signature lattice — the case dispatch (uniform/scan/wave/
@@ -930,6 +941,8 @@ class Scheduler:
     def _invalidate_device_state(self) -> None:
         self._device_carry = None
         self._ext_mutations += 1
+        # the reseed reads the snapshot, which has the deletes already
+        self._retires, self._retire_folds = [], []
 
     def _on_pod_add(self, pod: Pod) -> None:
         self.workload_manager.add_pod(pod)
@@ -1124,12 +1137,78 @@ class Scheduler:
         self._shard_parked.pop(pod.uid, None)
         self.journey.forget(pod.uid)
         if pod.spec.node_name:
+            retire = self._retire_of(pod)
             self.cache.remove_pod(pod)
-            self._invalidate_device_state()
+            if retire is None:
+                self._invalidate_device_state()
+                self.metrics.pod_delete_carry.inc("reseed")
+            else:
+                # still an external event (the audit compares failure
+                # reasons only across drains none separates), but the
+                # carry stays resident
+                self._retires.append(retire)
+                self._ext_mutations += 1
+                self.metrics.pod_delete_carry.inc("delta")
             self.queue.move_all_to_active_or_backoff_queue(
                 EVENT_ASSIGNED_POD_DELETE, pod, None)
         else:
             self.queue.delete(pod)
+
+    def _retire_of(self, pod: Pod) -> Optional[tuple[int, int]]:
+        """(signature row, node row) of a bound pod whose delete the
+        resident carry takes as a negative placement, or None when the
+        carry must be reseeded instead: no resident carry, a mesh, live
+        nominations (the overlay), a pod with volumes or claims (their
+        bindings live outside the carry), a pod the device did not place
+        from a still-current row (host-path pods, pods bound by others, a
+        table reset since: it replaces the list the row token holds), a
+        row or row capacity the carry's group tensors were not seeded
+        for, or a node outside the matrix."""
+        carry = self._device_carry
+        if (carry is None or self.mesh is not None
+                or self.queue.nominator.nominated_pods
+                or pod.spec.volumes or pod.spec.resource_claims):
+            return None
+        ps = self.cache.pod_states.get(pod.uid)
+        row = ps.row if ps is not None else None
+        b = self.builder
+        if row is None or row[0] is not b.row_facts:
+            return None
+        n = carry.npods.shape[0]
+        if carry.groups is not None and (
+                row[1] >= self._seeded_rows
+                or (b.groups.device_rows(), n) != self._gd_capacity):
+            return None
+        node = self.state.node_index.get(ps.pod.spec.node_name)
+        if node is None or node >= n:
+            return None
+        return row[1], node
+
+    def _fold_retires(self, carry):
+        """Fold the pending retires out of `carry` in one `retire_pods`
+        dispatch and make the result the resident carry. The fold is kept
+        until the next drain is dispatched: a replay of the drains in
+        flight folds it again at the same point of the chain."""
+        with self.ingest_lock:   # the delete handlers append under it
+            retires, self._retires = self._retires, []
+        rows = np.array([r for r, _ in retires], np.int32)
+        nodes = np.array([n for _, n in retires], np.int32)
+        wt, slot = np.unique(rows, return_inverse=True)
+        S = pow2_at_least(len(wt), 1)
+        cnt = np.zeros((S, carry.npods.shape[0]), np.int32)
+        np.add.at(cnt, (slot, nodes), 1)
+        wt = np.resize(wt, S)   # padding repeats rows with zero counts
+        t = self.builder.table
+        rt = Retire(wt=wt, cnt=cnt, req=t.req[wt],
+                    nonzero_req=t.nonzero_req[wt], port_ids=t.port_ids[wt])
+        from .perf.ledger import GLOBAL as _ledger
+        _ledger.note_h2d_tree("host_snapshot", rt)
+        gd, fam = ((self._gd_dev, self._gd_fam) if carry.groups is not None
+                   else (None, None))
+        fold = (Retire(*(jnp.asarray(x) for x in rt)), gd, fam)
+        self._retire_folds.append(fold)
+        self._device_carry = retire_pods(carry, *fold)
+        return self._device_carry
 
     def _on_pvc_change(self, *args) -> None:
         """PVC add/update can unblock VolumeBinding rejects
@@ -1759,13 +1838,20 @@ class Scheduler:
                     or carry.used.shape != na.used.shape
                     or groups_needed != (carry.groups is not None)
                     or (groups_needed and capacity != self._gd_capacity)):
-                # structural change: reseed from the host snapshot
+                # structural change: reseed from the host snapshot, which
+                # has the pending deletes already
                 carry = None
+                self._retires, self._retire_folds = [], []
                 with self._phase("host_snapshot", ph):
                     self._drain_pending()
                     self.cache.update_snapshot(self.snapshot)
                     self.state.apply_snapshot(self.snapshot)
                 na = self._node_arrays()
+            if carry is not None and self._retires:
+                # before any new rows seed from the live snapshot (which
+                # has the deletes already): the fold leaves them alone
+                with self._phase("host_snapshot", ph):
+                    carry = self._fold_retires(carry)
             with self._phase("host_group_seed", ph, groups=groups_needed):
                 if carry is None:
                     gcarry = None
@@ -1953,7 +2039,8 @@ class Scheduler:
             na=na, n=n, groups_needed=groups_needed, records=records,
             dispatched_at=t0, ovl=ovl, nom=nom, phases=ph, drain_id=did,
             gang=gang, facts=self.builder.row_facts, audit=audit_rec,
-            probe=probe, kernels=kernels))
+            probe=probe, kernels=kernels, retire=tuple(self._retire_folds)))
+        self._retire_folds = []
         return 0
 
     @contextmanager
@@ -2856,6 +2943,8 @@ class Scheduler:
         prev_profile = pd.profile
         prev_ovl = pd.ovl
         for pd2 in self._pending:
+            for fold in pd2.retire:
+                carry = retire_pods(carry, *fold)
             if pd2.profile is not prev_profile or pd2.ovl is not prev_ovl:
                 carry = carry._replace(
                     cache=carry.cache._replace(sig=jnp.int32(0)))
@@ -2865,6 +2954,8 @@ class Scheduler:
                 pd2.profile, pd2.na, carry, pd2.batch, pd2.table,
                 pd2.n, pd2.groups_needed, ovl=pd2.ovl, nom=pd2.nom,
                 gang=(pd2.gang[1] if pd2.gang is not None else None))
+        for fold in self._retire_folds:
+            carry = retire_pods(carry, *fold)
         if self._device_carry is not None:
             self._device_carry = carry
 
@@ -3333,7 +3424,8 @@ class Scheduler:
         self._drain_pending()
         self.cache.update_snapshot(self.snapshot)
         if self._device_carry is not None:
-            c = self._device_carry
+            c = (self._fold_retires(self._device_carry) if self._retires
+                 else self._device_carry)
             gens = {ni.name: ni.generation
                     for ni in self.snapshot.node_info_list}
             self.state.adopt_carry(c.used, c.nonzero_used, c.npods, c.ports,

@@ -10,6 +10,8 @@ plugin on random small states; and the placement-tier counter and the
 `ipa_seed` phase are checked where they are incremented.
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -53,10 +55,16 @@ TRAFFIC = {"backlog": 32, "create_chunk": 16, "warmup_iterations": 1,
            "warmup_max_s": 60}
 
 
-def _closed_loop(cfg: dict, seed: int, strip: bool = False):
-    """24 iterations of the benchmark's loop and the grace after; with
-    `strip` the program's pods lose their anti term (the reference keeps
-    it). Returns the system and the reference's findings."""
+def _closed_loop(cfg: dict, seed: int, strip: bool = False,
+                 completions: int = 0):
+    """24 iterations of the benchmark's loop, then more until at least
+    `completions` pods were completed, and the grace after; with `strip`
+    the program's pods lose their anti term (the reference keeps it).
+    Returns the system and the reference's findings.
+
+    The loop does not wait for a drain: on a loaded machine the first
+    drain may still be in flight after 24 iterations, so a run that must
+    see completions goes on until it has, pausing while nothing binds."""
     system = System(cfg, Builder(cfg, ref))
     system.create_nodes()
     system.sched.batch_size = 32       # a full drain per call
@@ -71,6 +79,12 @@ def _closed_loop(cfg: dict, seed: int, strip: bool = False):
     drv = Driver(system, cfg, TRAFFIC, seed)
     for _ in range(24):
         drv._iteration()
+    deadline = time.monotonic() + 120.0
+    while len(system.deletes) < completions and time.monotonic() < deadline:
+        bound = len(system.watch.node)
+        drv._iteration()
+        if len(system.watch.node) == bound:
+            time.sleep(0.01)
     drv.finish(60.0)
     record = system.record(range(system.created))
     sample = np.random.default_rng(seed).choice(
@@ -86,14 +100,17 @@ def test_closed_loop_matches_reference(seed):
     a backlog of 32 pods not yet bound: 8 + 24 + 32 <= 128, so every pod
     finds a node of its own."""
     cfg = _config(128, 8, 24)
-    system, record, found = _closed_loop(cfg, seed)
+    system, record, found = _closed_loop(cfg, seed, completions=48)
     assert {k: found[k] for k in ("unbound", "bind_conflicts", "infeasible",
                                   "off_argmax")} == dict.fromkeys(
         ("unbound", "bind_conflicts", "infeasible", "off_argmax"), 0)
-    # the init pods were never completed, the measure pods were
+    # the init pods were never completed, the measure pods were, twice
+    # over: each delete folded out of the resident carry, none reseeded it
     gone = {s for _, s in record.deletes}
-    assert gone and min(gone) >= 8
+    assert len(gone) >= 48 and min(gone) >= 8
     m = system.sched.metrics
+    assert m.pod_delete_carry.value("delta") == len(record.deletes)
+    assert m.pod_delete_carry.value("reseed") == 0
     assert m.placement_pods.value("wave_merge") > 0
     assert sum(m.schedule_attempts.value("unschedulable", p)
                for p in system.sched.profiles) == 0

@@ -2,11 +2,13 @@
 run here.
 
 - AOT compiles of `run_batch`, `run_uniform`, `scatter_rows` and `run_gang`
-  at the 5000-node bucket (8192 node rows, 8192-pod batch, int64), and of
+  at the 5000-node bucket (8192 node rows, 8192-pod batch, int64), of
   `run_wave` for a 512-pod hostname anti-affinity span (the inter-pod
-  families of its wave fold), for one described v5e device: what the
-  chip's compiler refuses fails here, at no chip time. Nothing runs, so
-  these say nothing about results or speed.
+  families of its wave fold), and of `retire_pods` folding deleted pods
+  out of the lean carry and out of that anti-affinity carry, for one
+  described v5e device: what the chip's compiler refuses fails here, at
+  no chip time. Nothing runs, so these say nothing about results or
+  speed.
 - chip_smoke.py's cases at their 500-node shapes with the accelerator
   branch of the device path forced (buffer donation), through the
   script's own case runner: every pod bound, nothing on the host, zero
@@ -184,6 +186,20 @@ def _lower(kernel: str, c: dict, spec):
         rows = jax.tree_util.tree_map(lambda a: a[:64], na)
         return P._scatter_rows_jit.lower(
             spec(na), spec(np.zeros(64, np.int32)), spec(rows))
+    if kernel.startswith("retire_pods"):
+        # one deleted signature row (Scheduler._fold_retires's S bucket),
+        # out of the anti-affinity carry or out of the lean one
+        a = c.get("anti")
+        carry = a["carry"] if a else c["carry0"]
+        gd, fam = (spec(a["gd"]), a["fam"]) if a else (None, None)
+        n_rows, width = carry.used.shape
+        rt = P.Retire(wt=np.zeros(1, np.int32),
+                      cnt=np.zeros((1, n_rows), np.int32),
+                      req=np.zeros((1, width), np.int64),
+                      nonzero_req=np.zeros((1, 2), np.int64),
+                      port_ids=np.zeros((1, c["table"].port_ids.shape[1]),
+                                        np.int32))
+        return P._retire_pods_jit.lower(spec(carry), spec(rt), gd, fam)
     if kernel == "run_wave":
         # Scheduler._wave_dispatch's statics for a 512-pod anti span
         a = c["anti"]
@@ -208,12 +224,13 @@ def _lower(kernel: str, c: dict, spec):
 
 
 @pytest.mark.parametrize("kernel", ["run_batch", "run_uniform",
-                                    "scatter_rows", "run_gang", "run_wave"])
+                                    "scatter_rows", "run_gang", "run_wave",
+                                    "retire_pods", "retire_pods_anti"])
 def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache,
                                  cluster, request):
     import jax
 
-    if kernel == "run_wave":
+    if kernel in ("run_wave", "retire_pods_anti"):
         cluster = dict(cluster, anti=request.getfixturevalue("anti_cluster"))
 
     def spec(tree):

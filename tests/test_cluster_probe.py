@@ -229,7 +229,7 @@ class TestProbeRegistration:
     def test_all_kernels_ledgered_and_sanitized(self):
         from kubernetes_tpu.perf.ledger import device_entries
         kernels = device_entries()
-        assert "cluster_probe" in kernels and len(kernels) == 18
+        assert "cluster_probe" in kernels and len(kernels) == 19
         assert "cluster_probe_sharded" in kernels
         # the declaration is the dispatch path: staged and measured
         assert kernels["cluster_probe"].fn is cluster_probe
