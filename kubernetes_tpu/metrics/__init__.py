@@ -297,6 +297,9 @@ POD_DELETE_CARRY_PATHS = ("delta", "reseed")
 # the placement tiers that resolve a drain's pods (placement_pods_total)
 PLACEMENT_TIERS = ("uniform", "wave_merge", "wave_serial", "wavescan", "scan",
                    "gang", "host_greedy", "host")
+# the plan program's scan steps, a span's pods or its pow2 padding
+# (plan_scan_steps_total)
+PLAN_SCAN_SLOTS = ("valid", "pad")
 CROSS_SHARD_OUTCOMES = ("conflict", "fenced")
 
 
@@ -506,6 +509,12 @@ class SchedulerMetrics:
             "(the host's vectorized greedy) and host (the per-pod host "
             "oracle).",
             ("tier",)))
+        self.plan_scan_steps = r.register(Counter(
+            n + "plan_scan_steps_total",
+            "Steps of the plan program's serial scan (run_plan), by slot: "
+            "valid (a pod of the span) or pad (the span's pow2 bucket "
+            "beyond its pods), counted at each dispatch.",
+            ("slot",)))
         self.events_total = r.register(Counter(
             n + "events_total",
             "Scheduling events emitted by the event recorder, by type "
@@ -782,6 +791,8 @@ class SchedulerMetrics:
             self.pod_delete_carry.inc(path, by=0)
         for tier in PLACEMENT_TIERS:
             self.placement_pods.inc(tier, by=0)
+        for slot in PLAN_SCAN_SLOTS:
+            self.plan_scan_steps.inc(slot, by=0)
         # remaining registered-but-unseeded series: dashboards and
         # bench_metrics.prom must carry every series even when the run
         # never observes them (no permit waits, no divergence, no events)

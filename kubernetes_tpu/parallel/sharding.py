@@ -44,9 +44,9 @@ from ..ops.groups import GroupCarry, GroupsDev, group_update
 from ..ops.program import (MAX_SCORE, Carry, PodRow, PodTableDev, PodXs,
                            ScoreConfig, SigCache, _apply_assignment,
                            _eval_pod, _fit_scores, _gather_row, _row_refresh,
-                           _uniform_matrix, _WaveState, balanced_allocation,
-                           default_normalize, fit_mask, least_allocated,
-                           ports_mask)
+                           _sum_terms, _uniform_matrix, _WaveState,
+                           balanced_allocation, default_normalize, fit_mask,
+                           least_allocated, ports_mask)
 from ..perf.ledger import GLOBAL as LEDGER, device_entry
 from ..state.tensorize import NodeArrays
 
@@ -775,11 +775,11 @@ def _plan_local(cfg: ScoreConfig, n_global: int, fam, norm_live: bool,
             tvb_c = own(stc_tv[:, :, lid_safe], in_shard)
             share_c = ((stc_tv == tvb_c[:, :, None])
                        & (tvb_c[:, :, None] != 0))
-            d_cons = jnp.sum(w_c[w][:, :, None] * share_c, axis=1)
+            d_cons = _sum_terms(w_c[w][:, :, None] * share_c)
             tvb_p = own(stp_tv[w, :, lid_safe], in_shard)
             share_p = ((stp_tv[w] == tvb_p[:, None])
                        & (tvb_p[:, None] != 0))
-            d_plcd = jnp.sum(w_p[w][:, :, None] * share_p[None], axis=1)
+            d_plcd = _sum_terms(w_p[w][:, :, None] * share_p[None])
             iscore = stx.iscore + assigned.astype(jnp.int64) * (
                 d_cons + d_plcd)
 

@@ -2306,6 +2306,8 @@ class Scheduler:
         has_groups = self._gd_dev is not None
         fam = self._gd_fam if has_groups else GroupFamilies(
             False, False, False, False, False)
+        self.metrics.plan_scan_steps.inc("valid", by=m)
+        self.metrics.plan_scan_steps.inc("pad", by=bucket - m)
         if self.mesh is not None:
             from .parallel.sharding import run_plan_sharded
             carry2, packed = run_plan_sharded(

@@ -4,11 +4,12 @@ run here.
 - AOT compiles of `run_batch`, `run_uniform`, `scatter_rows` and `run_gang`
   at the 5000-node bucket (8192 node rows, 8192-pod batch, int64), of
   `run_wave` for a 512-pod hostname anti-affinity span (the inter-pod
-  families of its wave fold), and of `retire_pods` folding deleted pods
-  out of the lean carry and out of that anti-affinity carry, for one
-  described v5e device: what the chip's compiler refuses fails here, at
-  no chip time. Nothing runs, so these say nothing about results or
-  speed.
+  families of its wave fold), of `run_plan` for a 512-pod span of a
+  self-affine zone row (required affinity and its hard-weight score), and
+  of `retire_pods` folding deleted pods out of the lean carry and out of
+  those two carries, for one described v5e device: what the chip's
+  compiler refuses fails here, at no chip time. Nothing runs, so these
+  say nothing about results or speed.
 - chip_smoke.py's cases at their 500-node shapes with the accelerator
   branch of the device path forced (buffer donation), through the
   script's own case runner: every pod bound, nothing on the host, zero
@@ -153,6 +154,54 @@ def anti_cluster():
             "mode": compiler._wave_same_mode(row)}
 
 
+@pytest.fixture(scope="module")
+def affinity_cluster():
+    """Host-side inputs at the 5000-node bucket for a self-affine required
+    zone affinity row (the SchedulingPodAffinity template: every node in
+    one zone) with resident affinity pods: the plan program's scan and
+    fold over the ipa_req and ipa_score families."""
+    from kubernetes_tpu.backend.cache import Cache, Snapshot
+    from kubernetes_tpu.compiler import DrainCompiler
+    from kubernetes_tpu.ops.groups import to_device
+    from kubernetes_tpu.ops.program import initial_carry, pod_rows_from_batch
+    from kubernetes_tpu.state.batch import BatchBuilder
+    from kubernetes_tpu.state.tensorize import ClusterState
+    from kubernetes_tpu.testing.wrappers import make_node, make_pod
+
+    zone = "topology.kubernetes.io/zone"
+
+    def peer(name, ns):
+        return (make_pod(name, ns).req({"cpu": "100m", "memory": "500Mi"})
+                .label("foo", "")
+                .pod_affinity(zone, {"foo": ""},
+                              namespaces=("sched-1", "sched-0")))
+
+    cache = Cache()
+    for i in range(NODES):
+        cache.add_node(make_node(f"n{i}").capacity(
+            {"cpu": 4, "memory": "32Gi", "pods": 110})
+            .label(zone, "zone1").obj())
+    for i in range(8):
+        cache.add_pod(peer(f"init-{i}", "sched-0").node(f"n{i}").obj())
+    snap = Snapshot()
+    cache.update_snapshot(snap)
+    state = ClusterState()
+    state.apply_snapshot(snap, full=True)
+    builder = BatchBuilder(state)
+    batch = builder.build([peer(f"p{i}", "sched-1").obj()
+                           for i in range(512)], pad_to=512)
+    na = state.device_arrays()
+    gd_np, gc_np = builder.groups.build_dev(snap)
+    _xs, table = pod_rows_from_batch(batch)
+    row = int(batch.tidx[0])
+    compiler = DrainCompiler(state=state, builder=builder, gates=None)
+    return {"na": na, "table": table, "row": row, "gd": to_device(gd_np),
+            "fam": builder.groups.families(snap),
+            "carry": initial_carry(na, to_device(gc_np)),
+            "statics": compiler.surfaces.stacked(na, table, (row, row)),
+            "mode": compiler._wave_same_mode(row)}
+
+
 def _uniform_lkj(L: int, n_rows: int):
     """Scheduler._uniform_shape / _gang_dispatch's (L, K, J) choice."""
     from kubernetes_tpu.state.tensorize import pow2_at_least
@@ -188,8 +237,8 @@ def _lower(kernel: str, c: dict, spec):
             spec(na), spec(np.zeros(64, np.int32)), spec(rows))
     if kernel.startswith("retire_pods"):
         # one deleted signature row (Scheduler._fold_retires's S bucket),
-        # out of the anti-affinity carry or out of the lean one
-        a = c.get("anti")
+        # out of an inter-pod carry or out of the lean one
+        a = c.get("ipa")
         carry = a["carry"] if a else c["carry0"]
         gd, fam = (spec(a["gd"]), a["fam"]) if a else (None, None)
         n_rows, width = carry.used.shape
@@ -202,13 +251,24 @@ def _lower(kernel: str, c: dict, spec):
         return P._retire_pods_jit.lower(spec(carry), spec(rt), gd, fam)
     if kernel == "run_wave":
         # Scheduler._wave_dispatch's statics for a 512-pod anti span
-        a = c["anti"]
+        a = c["ipa"]
         _mode, anti_term = a["mode"]
         return P._run_wave_same_fn(True).lower(
             cfg, spec(a["na"]), spec(a["carry"]),
             spec(np.ones(BATCH_WAVE, bool)), spec(a["table"]),
             spec(np.int32(a["row"])), spec(a["gd"]), spec(a["statics"]),
             BATCH_WAVE, 1, BATCH_WAVE, a["fam"], False, anti_term, True)
+    if kernel == "run_plan":
+        # Scheduler._wavescan_dispatch's statics for a 512-pod span of one
+        # row: the signature set padded to the lattice's 2, no taints or
+        # node affinity (static normalisation)
+        a = c["ipa"]
+        xs = P.WaveXs(valid=np.ones(BATCH_WAVE, bool),
+                      widx=np.zeros(BATCH_WAVE, np.int32))
+        return P._run_plan_fn(True).lower(
+            cfg, spec(a["na"]), spec(a["carry"]), spec(xs),
+            spec(a["table"]), spec(np.array([a["row"]] * 2, np.int32)),
+            spec(a["gd"]), spec(a["statics"]), a["fam"], False, True, False)
     assert kernel == "run_gang"
     # the scan tier: the uniform tier shares run_uniform's _uniform_core
     row = int(batch.tidx[0])
@@ -225,13 +285,20 @@ def _lower(kernel: str, c: dict, spec):
 
 @pytest.mark.parametrize("kernel", ["run_batch", "run_uniform",
                                     "scatter_rows", "run_gang", "run_wave",
-                                    "retire_pods", "retire_pods_anti"])
+                                    "run_plan", "retire_pods",
+                                    "retire_pods_anti",
+                                    "retire_pods_affinity"])
 def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache,
                                  cluster, request):
     import jax
 
     if kernel in ("run_wave", "retire_pods_anti"):
-        cluster = dict(cluster, anti=request.getfixturevalue("anti_cluster"))
+        cluster = dict(cluster, ipa=request.getfixturevalue("anti_cluster"))
+    elif kernel in ("run_plan", "retire_pods_affinity"):
+        cluster = dict(cluster,
+                       ipa=request.getfixturevalue("affinity_cluster"))
+        assert cluster["ipa"]["mode"] == (None, -1)     # off the merge wave
+        assert cluster["ipa"]["fam"].ipa_req and cluster["ipa"]["fam"].ipa_score
 
     def spec(tree):
         return jax.tree_util.tree_map(
